@@ -457,8 +457,8 @@ def _backend_rows():
     contract of tests/test_backends.py at network scale) and both the
     emulated and modeled cycle totals must be bit-identical (backends
     re-time execution, never the model).  A third subsecond record times
-    the ``pallas-interpret`` adapter on one packed dot (gated on byte-
-    identity to host), so the interpret path's wall cost is tracked in
+    the ``pallas`` adapter on one packed dot (gated on byte-identity to
+    host), so the interpret path's wall cost is tracked in
     the same baseline.  Interleaved min-of-2 so shared-host noise
     cancels; per-backend wall times are EXPECTED to differ — that is the
     point of the records — only values and cycles are gated."""
@@ -516,18 +516,18 @@ def _backend_rows():
     ref, _ = bs.packed_dot_words(xw, ww, K=144, acc_bits=32, engine="host")
     nc_backends.dispatch_stats_clear()
     vals, _ = bs.packed_dot_words(xw, ww, K=144, acc_bits=32,
-                                  engine="pallas-interpret")
+                                  engine="pallas")
     if not np.array_equal(np.asarray(vals), np.asarray(ref)):
-        raise RuntimeError("backend gate: pallas-interpret packed dot "
+        raise RuntimeError("backend gate: pallas packed dot "
                            "diverges from the host backend")
-    if nc_backends.dispatch_stats()["pallas-interpret"]["native"] != 1:
-        raise RuntimeError("backend gate: pallas-interpret delegated the "
+    if nc_backends.dispatch_stats()["pallas"]["native"] != 1:
+        raise RuntimeError("backend gate: pallas delegated the "
                            "in-envelope dot to host — the record would "
                            "time the wrong body")
     out.append(_timed_rec(
-        "backend/pallas-interpret/dot",
+        "backend/pallas/dot",
         lambda: bs.packed_dot_words(xw, ww, K=144, acc_bits=32,
-                                    engine="pallas-interpret"), 3,
+                                    engine="pallas"), 3,
         "13x144 . 8x144 word grids",
         "interpret-mode Pallas GEMM, byte-identical to host"))
     return out

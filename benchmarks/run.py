@@ -236,7 +236,9 @@ def _run_slow_gate() -> bool:
     cmd = [sys.executable, "-m", "pytest", "-q", "-m",
            "slow or faults or backends", "-o", "addopts=", "tests"]
     print(f"# slow-test gate: {' '.join(cmd[2:])}", file=sys.stderr)
-    res = subprocess.run(cmd, cwd=repo)
+    # CPU tests: the child must not reach for the chip this process holds
+    res = subprocess.run(cmd, cwd=repo,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     return res.returncode in (0, 5)  # 5: no slow tests collected
 
 
@@ -346,6 +348,8 @@ def main() -> None:
                          "kernel_bench.RETIMERS, median of 3, diffed "
                          "against the baseline; never rewrites it")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.quick and args.only:
         ap.error("--quick and --only are mutually exclusive")
     if args.only:

@@ -20,14 +20,17 @@ Contract
   reference exactly (tests/test_backends.py runs the differential
   conformance harness over the full operating envelope).  A backend may
   delegate inputs outside its native envelope (capability flags below)
-  to the host body — delegation is counted in :func:`dispatch_stats` so
-  tests can assert the native path actually ran.
+  to the host body on CPU — delegation is counted in
+  :func:`dispatch_stats` so tests can assert the native path actually
+  ran.
 * **Selection is configuration.**  Precedence at every call site:
   explicit ``engine=`` argument > the plan's ``backend`` field
   (``schedule.plan_layer(backend=...)`` — the same plan-decision idiom
   as sparsity/overlap/integrity/compression) > the ``NC_BACKEND``
-  environment variable > the caller's default.  An explicit engine
-  that *contradicts* a backend-carrying plan raises (ambiguous).
+  environment variable > ``pallas`` where the platform is a TPU > the
+  caller's default (host, or jit for batched Inception forwards).  An
+  explicit engine that *contradicts* a backend-carrying plan raises
+  (ambiguous).
 
 Registered backends
 -------------------
@@ -42,26 +45,34 @@ Registered backends
     (x planes, w planes, acc, K) bucket (``bitserial.engine_cache_info``
     reports the cache).  Falls back to host when the int32 decode could
     overflow.
-``pallas-interpret``
+``pallas``
     The byte-packed Pallas bit-serial GEMM (in-kernel shift+mask plane
     unpack, zero-plane-block skip; the W4A4 nibble kernel when both
-    operands fit 4 planes) run through the Pallas interpreter on CPU.
-    A real-TPU deployment is the SAME adapter with ``interpret=False``
-    — ``kernels/ops.py`` flips that off ``ops.on_tpu()`` — registered
-    as one new entry plus one bench refresh.  Inputs outside its native
-    envelope (traced operands, rows sharing words — ``K <= 16`` —,
-    > 8 planes, int32-overflow risk, non-separable broadcast grids,
-    oversized tiles) delegate to host, exactly.
+    operands fit 4 planes).  The word grids decode to integer rows on
+    the device inside one jitted program with the kernel; ragged tiles
+    pad to :func:`~repro.core.bitserial.bucket_words` rows so repeated
+    tile shapes share one executable.  Compiled on a TPU, run through
+    the Pallas interpreter on CPU (``kernels/ops.py`` decides).  On CPU,
+    inputs outside its native envelope (traced operands, rows sharing
+    words — ``K <= 16`` —, > 8 planes, int32-overflow risk,
+    non-separable broadcast grids, oversized tiles) delegate to host,
+    exactly; on a TPU they raise instead — the device path never
+    answers from the host.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from typing import Callable
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bitserial as bs
+from repro.kernels import ops
+from repro.kernels import ref as kref
 
 __all__ = [
     "Backend",
@@ -84,9 +95,10 @@ class Backend:
     """One registered execution body for the packed bit-serial dot.
 
     The capability flags describe the *native* envelope; inputs outside
-    it are delegated to the host body (still byte-exact — see the module
-    contract).  ``dot_words(xw, ww, *, K, acc_bits, materialize)``
-    returns the integer row values only; cycles are charged by the
+    it are delegated to the host body on CPU (still byte-exact — see the
+    module contract) and raise on a TPU.
+    ``dot_words(xw, ww, *, K, acc_bits, materialize)`` returns the
+    integer row values only; cycles are charged by the
     caller (``bitserial.packed_dot_words``) so backends cannot perturb
     the cycle model."""
 
@@ -141,23 +153,26 @@ def env_backend() -> str | None:
 
 
 def default_backend() -> str:
-    """``NC_BACKEND`` when set (validated), else the host reference."""
-    return env_backend() or "host"
+    """``NC_BACKEND`` when set (validated), else ``pallas`` on a TPU,
+    else the host reference."""
+    return resolve_backend()
 
 
 def resolve_backend(explicit: str | None = None,
                     plan_backend: str | None = None,
                     default: str | None = None) -> str:
     """Resolve the backend name by the standing precedence: explicit
-    ``engine=`` > plan's ``backend`` field > ``NC_BACKEND`` > ``default``
-    (the host reference when no default is given).  Callers raise on the
-    ambiguous explicit-vs-plan combination *before* resolving; here an
-    explicit name simply wins (they are checked equal upstream)."""
+    ``engine=`` > plan's ``backend`` field > ``NC_BACKEND`` > ``pallas``
+    on a TPU > ``default`` (the host reference when no default is
+    given).  Callers raise on the ambiguous explicit-vs-plan combination
+    *before* resolving; here an explicit name simply wins (they are
+    checked equal upstream)."""
     if explicit is not None:
         return get_backend(explicit).name
     if plan_backend is not None:
         return get_backend(plan_backend, source="plan backend").name
-    return env_backend() or (default if default is not None else "host")
+    return (env_backend() or ("pallas" if ops.on_tpu() else None)
+            or default or "host")
 
 
 def dispatch_stats() -> dict[str, dict[str, int]]:
@@ -191,11 +206,6 @@ def _host_dot_words(xw, ww, *, K: int, acc_bits: int,
 # engine_cache_info/engine_cache_clear keep reporting it)
 # ---------------------------------------------------------------------------
 def _jit_dot_words(xw, ww, *, K: int, acc_bits: int, materialize: bool = True):
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
     if bs._is_traced(xw, ww):
         _note("jit", native=False)
         return bs._dot_words_impl(xw, ww, K=K, acc_bits=acc_bits)
@@ -216,23 +226,10 @@ def _jit_dot_words(xw, ww, *, K: int, acc_bits: int, materialize: bool = True):
 
 
 # ---------------------------------------------------------------------------
-# pallas-interpret — the byte-packed Pallas GEMM as a word-grid adapter
+# pallas — the byte-packed Pallas GEMM as a word-grid adapter
 # ---------------------------------------------------------------------------
-def _decode_rows(words: np.ndarray, K: int) -> np.ndarray:
-    """Row-aligned word grid ``(n, *grid, wpr)`` -> ``(*grid, K)`` int64
-    lane values (P >= 32 layouts only: one row per grid element)."""
-    n = words.shape[0]
-    bits = bs._unpack_bits32_np(words)  # (n, *grid, wpr, 32)
-    weights = (np.int64(1) << np.arange(n, dtype=np.int64)).reshape(
-        (n,) + (1,) * (bits.ndim - 1))
-    vals = (bits.astype(np.int64) * weights).sum(axis=0)  # (*grid, wpr, 32)
-    return vals.reshape(vals.shape[:-2] + (-1,))[..., :K]
-
-
 def _pallas_fallback_reason(xw, ww, *, K: int, acc_bits: int,
                             backend: Backend) -> str | None:
-    import jax
-
     if bs._is_traced(xw, ww):
         return "traced operands"
     nx, nw = int(xw.shape[0]), int(ww.shape[0])
@@ -257,40 +254,67 @@ def _pallas_fallback_reason(xw, ww, *, K: int, acc_bits: int,
     return None
 
 
+def _decode_rows_dev(words, K: int):
+    """Row-aligned word grid ``(n, R, wpr)`` uint32 -> ``(R, K)`` int32
+    lane values, on the device (P >= 32 layouts: one row per grid row)."""
+    n, R, wpr = words.shape
+    shifts = jnp.arange(32, dtype=jnp.uint32)
+    bits = (words[..., None] >> shifts) & 1  # (n, R, wpr, 32)
+    bits = bits.reshape(n, R, wpr * 32)[..., :K].astype(jnp.int32)
+    weights = (jnp.int32(1) << jnp.arange(n, dtype=jnp.int32))[:, None, None]
+    return (bits * weights).sum(axis=0)
+
+
+def _pallas_exact_impl(xf, wf, *, K: int, w4a4: bool):
+    """Decode both operands' word grids and run the exact unsigned Pallas
+    GEMM: ``(Rx, K) @ (K, Rw)`` int32, one jitted program per shape."""
+    X = _decode_rows_dev(xf, K)
+    planes = _decode_rows_dev(wf, K).T.astype(jnp.uint8)  # byte == planes
+    n_w = int(wf.shape[0])
+    if w4a4:
+        x_nib = kref.pack_activation_nibbles(X.astype(jnp.int8))
+        return ops.bitserial_matmul_exact(x_nib, planes, n_bits=n_w,
+                                          w4a4=True)
+    return ops.bitserial_matmul_exact(X.astype(jnp.uint8), planes,
+                                      n_bits=n_w)
+
+
+_pallas_exact = jax.jit(_pallas_exact_impl, static_argnames=("K", "w4a4"))
+
+
+def _pad_rows(words: np.ndarray, rows: int) -> np.ndarray:
+    pad = rows - words.shape[1]
+    return np.pad(words, ((0, 0), (0, pad), (0, 0))) if pad else words
+
+
 def _pallas_dot_words(xw, ww, *, K: int, acc_bits: int,
                       materialize: bool = True):
-    """Adapter: decode the two row-aligned word grids to integer row
-    matrices, run the byte-packed Pallas kernel (interpret mode off-TPU;
-    the W4A4 nibble kernel when both operands fit 4 planes), and scatter
-    the exact int32 accumulator back into the broadcast grid."""
-    import jax.numpy as jnp
-
-    from repro.kernels import ops
-    from repro.kernels import ref as kref
-
-    backend = _REGISTRY["pallas-interpret"]
+    """Adapter: flatten the two row-aligned word grids, pad their rows to
+    the bucketed-jit engine's buckets, decode and run the byte-packed
+    Pallas kernel in one device program (the W4A4 nibble kernel when
+    both operands fit 4 planes), and scatter the exact int32 accumulator
+    back into the broadcast grid."""
+    backend = _REGISTRY["pallas"]
     reason = _pallas_fallback_reason(xw, ww, K=K, acc_bits=acc_bits,
                                      backend=backend)
     if reason is not None:
-        _note("pallas-interpret", native=False)
+        if ops.on_tpu():
+            raise ValueError(f"pallas backend: input outside the native "
+                             f"envelope ({reason}); the TPU path has no "
+                             f"host fallback")
+        _note("pallas", native=False)
         return bs._dot_words_impl(xw, ww, K=K, acc_bits=acc_bits)
-    _note("pallas-interpret", native=True)
+    _note("pallas", native=True)
 
     nx, nw = int(xw.shape[0]), int(ww.shape[0])
     gx, gw = xw.shape[1:-1], ww.shape[1:-1]
-    X = _decode_rows(np.asarray(xw), K).reshape(-1, K)  # [Rx, K]
-    W = _decode_rows(np.asarray(ww), K).reshape(-1, K)  # [Rw, K]
-
+    xf = np.asarray(xw, np.uint32).reshape(nx, -1, xw.shape[-1])
+    wf = np.asarray(ww, np.uint32).reshape(nw, -1, ww.shape[-1])
+    Rx, Rw = xf.shape[1], wf.shape[1]
     w4a4 = backend.w4a4 and nx <= 4 and nw <= 4 and K >= 2
-    planes = kref.pack_bitplanes_bytes(jnp.asarray(W.T, jnp.int32), nw)
-    if w4a4:
-        x_nib = kref.pack_activation_nibbles(jnp.asarray(X, jnp.int8))
-        out = ops.bitserial_matmul_exact(x_nib, planes, n_bits=nw,
-                                         w4a4=True)
-    else:
-        out = ops.bitserial_matmul_exact(jnp.asarray(X, jnp.int32), planes,
-                                         n_bits=nw)
-    O = np.asarray(out, np.int64)  # [Rx, Rw] exact int32 accumulator
+    out = _pallas_exact(_pad_rows(xf, bs.bucket_words(Rx)),
+                        _pad_rows(wf, bs.bucket_words(Rw)), K=K, w4a4=w4a4)
+    O = np.asarray(out, np.int64)[:Rx, :Rw]  # exact int32 accumulator
 
     # scatter back into the broadcast grid: each grid axis is owned by at
     # most one operand (separability checked above), so interleaving the
@@ -309,6 +333,6 @@ register_backend(Backend(
     name="jit", acc_bits=None, w4a4=True, compressed_planes=True,
     integrity=True, max_lane_words=None, dot_words=_jit_dot_words))
 register_backend(Backend(
-    name="pallas-interpret", acc_bits=(24, 32), w4a4=True,
+    name="pallas", acc_bits=(24, 32), w4a4=True,
     compressed_planes=True, integrity=True, max_lane_words=1 << 22,
     dot_words=_pallas_dot_words))

@@ -74,9 +74,9 @@ recompiling per lane shape.  ``engine_cache_info()`` reports the cache
 contents.
 
 The ``engine=`` string names an entry in the explicit backend registry
-(core/backends.py — ``host``, ``jit``, ``pallas-interpret``; an unknown
+(core/backends.py — ``host``, ``jit``, ``pallas``; an unknown
 name raises listing the registered set, ``None`` resolves through the
-``NC_BACKEND`` environment variable).  Backends return values only;
+``NC_BACKEND`` environment variable, then the platform).  Backends return values only;
 :func:`packed_dot_words` charges :func:`dot_cycles` before dispatch, so
 modeled cycles are bit-identical across backends by construction.
 
@@ -1223,14 +1223,12 @@ def engine_cache_info() -> dict:
     """Bucketed-jit compilation cache: entries keyed by
     (n_bits_x, n_bits_w, acc_bits, K) with jit-internal shape caches.
 
-    ``compiled`` counts executables via the jitted function's private
-    ``_cache_size`` and is best-effort: it reads 0 if a future JAX drops
-    that attribute (``entries`` is always exact)."""
+    ``compiled`` counts executables via the jitted function's
+    ``_cache_size``."""
     return {
         "entries": len(_ENGINE_CACHE),
         "keys": sorted(_ENGINE_CACHE),
-        "compiled": sum(getattr(f, "_cache_size", lambda: 0)()
-                        for f in _ENGINE_CACHE.values()),
+        "compiled": sum(f._cache_size() for f in _ENGINE_CACHE.values()),
     }
 
 
@@ -1316,9 +1314,10 @@ def packed_dot_words(xw, ww, *, K: int, acc_bits: int,
     decoded-lane kernel (one executable per (planes, acc, K) bucket —
     callers pad their tile grids to :func:`bucket_words` sizes so ragged
     tails replay the cached executable; :func:`engine_cache_info` reports
-    the cache), ``"pallas-interpret"`` the byte-packed Pallas bit-serial
+    the cache), ``"pallas"`` the byte-packed Pallas bit-serial
     GEMM.  ``engine=None`` resolves through the ``NC_BACKEND``
-    environment variable (default host); an unknown name raises a
+    environment variable (default ``pallas`` on a TPU, else host); an
+    unknown name raises a
     :class:`ValueError` listing the registered backends.
 
     ``materialize=False`` skips the blocking device->host copy on the jit

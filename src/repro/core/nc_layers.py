@@ -283,10 +283,11 @@ def nc_conv2d(
     the untiled single-image formulation.
 
     ``engine`` names a registered backend (``core/backends.py``:
-    ``host``, ``jit``, ``pallas-interpret``, ...); ``None`` resolves by
+    ``host``, ``jit``, ``pallas``, ...); ``None`` resolves by
     the standing precedence explicit ``engine=`` > the plan's
     ``backend`` field (``plan_layer(..., backend=...)``) > the
-    ``NC_BACKEND`` environment variable > host.  An explicit engine that
+    ``NC_BACKEND`` environment variable > ``pallas`` on a TPU > host.
+    An explicit engine that
     contradicts a backend-carrying plan raises (the plan already
     decided).  ``engine="jit"`` runs tiles through the bucketed compiled
     engine (tiles are padded to a uniform shape so one executable serves
@@ -431,7 +432,8 @@ def nc_conv2d(
                                 quarantined_slices=quarantined,
                                 compressed=compressed, backend=backend_pin)
     # backend selection is pure configuration: explicit engine= > the
-    # plan's pin > NC_BACKEND > host (contradictions raised above)
+    # plan's pin > NC_BACKEND > pallas on a TPU > host (contradictions
+    # raised above)
     engine = _backends.resolve_backend(engine, plan.backend)
     tile_rows = max(1, min(plan.tile_rows, rows_total))
     tile_filters = max(1, min(plan.tile_filters, M))

@@ -32,14 +32,9 @@ __all__ = ["collective_bytes", "xla_cost_analysis", "CollectiveStats",
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to a dict.
+    """``compiled.cost_analysis()`` — one dict of XLA's cost counters."""
+    return dict(compiled.cost_analysis() or {})
 
-    JAX has flip-flopped between returning a dict and a one-element list of
-    dicts (one per computation) across releases; accept both."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
 
 DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,

@@ -36,23 +36,8 @@ __all__ = [
     "make_param_shardings",
     "make_batch_sharding",
     "make_cache_shardings",
-    "current_abstract_mesh",
     "ShardingReport",
 ]
-
-
-def current_abstract_mesh():
-    """The mesh installed by set_mesh / ``with mesh:`` at trace time, or
-    None.  ``jax.sharding.get_abstract_mesh`` where it exists; older JAX
-    exposes the same context via ``thread_resources.env.physical_mesh``."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as _mesh_lib  # pre-get_abstract_mesh releases
-    phys = getattr(_mesh_lib.thread_resources.env, "physical_mesh", None)
-    if phys is None or phys.empty:
-        return None
-    return phys.abstract_mesh
 
 
 @dataclasses.dataclass

@@ -17,14 +17,26 @@ Properties carried over from the paper:
   * beyond-paper: *zero-plane skipping* — a per-(plane, K-block, N-block)
     occupancy mask predicates all-zero plane-blocks off with @pl.when,
     exploiting bit-level sparsity the SRAM substrate cannot (it must clock
-    every bit-slice).  Pass ``plane_mask`` precomputed at weight-load time
-    (plane_block_mask over the unpacked planes); otherwise it is derived
-    from the byte-packed tensor on every call, which transiently
-    materializes the full [n_bits, K, N] plane stack.
+    every bit-slice).  The mask rides in SMEM as a scalar-prefetch operand
+    indexed by the grid position.  Pass ``plane_mask`` precomputed at
+    weight-load time (plane_block_mask over the unpacked planes, at the
+    padded block grid); otherwise it is derived from the byte-packed
+    tensor on every call.
+
+MXU operands: the signed path feeds int8 activations and int8 {0,1}
+planes (int32 accumulation).  The unsigned exact path (activations
+0..255, the packed word engine's convention) feeds bf16 operands with f32
+accumulation, exact per K block while ``bk * 255 < 2**24``; each block's
+partial product converts to the int32 accumulator exactly.
+
+Every operand is zero-padded to the TPU tiling (rows to 32, K and N to
+128 lanes) so each block satisfies the (32, 128) int8 tile; padding
+contributes zeros and is sliced off.
 
 Grid: (M/bm, N/bn, K/bk) with K innermost; planes of one (bk, bn) tile are
 looped inside the kernel body (static python loop -> fully unrolled MXU
-passes over the VMEM-resident byte tile).
+passes over the VMEM-resident byte tile).  ``interpret`` has no default:
+``kernels/ops.py`` decides it from the platform.
 """
 from __future__ import annotations
 
@@ -35,8 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import (pack_bitplanes_bytes, plane_weights,
-                               unpack_bitplanes_bytes)
+from repro.kernels.ref import pack_bitplanes_bytes, unpack_bitplanes_bytes
 
 # W4A4 (bitserial_matmul_a4): byte-packing extends to the *activation*
 # operand — two 4-bit elements per byte (ref.pack_activation_nibbles), so
@@ -50,14 +61,44 @@ DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 256
 
+_SUBLANE = 32  # int8/uint8 second-minor tile
+_LANE = 128
+_BF16_EXACT = 1 << 24  # f32 accumulation is exact below this magnitude
 
-def _plane_w(n_bits: int, signed: bool) -> jax.Array:
-    """Per-plane scales: two's complement (MSB negative) for the signed
-    quantized-GEMM convention, plain powers of two for the UNSIGNED
-    operands of the packed word engine (core/backends.py adapter)."""
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _blocks(M: int, N: int, K: int, bm: int, bn: int, bk: int):
+    """Tile-aligned block sizes and the padded extents they divide."""
+    bm = min(bm, _round_up(M, _SUBLANE))
+    bn = min(bn, _round_up(N, _LANE))
+    bk = min(bk, _round_up(K, _LANE))
+    return (bm, bn, bk), (_round_up(M, bm), _round_up(N, bn),
+                          _round_up(K, bk))
+
+
+def _pad2(a: jax.Array, rows: int, cols: int) -> jax.Array:
+    pr, pc = rows - a.shape[0], cols - a.shape[1]
+    return jnp.pad(a, ((0, pr), (0, pc))) if pr or pc else a
+
+
+def _plane_w(n_bits: int, signed: bool) -> list[int]:
+    """Per-plane scales as static ints: two's complement (MSB negative)
+    for the signed quantized-GEMM convention, plain powers of two for the
+    UNSIGNED operands of the packed word engine (core/backends.py)."""
+    pw = [1 << b for b in range(n_bits)]
     if signed:
-        return plane_weights(n_bits)
-    return 2 ** jnp.arange(n_bits, dtype=jnp.int32)
+        pw[-1] = -pw[-1]
+    return pw
+
+
+def _scales(x_scale, w_scale, N: int, Np: int):
+    """x_scale as a (1, 1) SMEM scalar, w_scale as a (1, Np) lane row."""
+    ws = jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32), (N,))
+    ws = _pad2(ws.reshape(1, N), 1, Np)
+    return jnp.reshape(jnp.asarray(x_scale, jnp.float32), (1, 1)), ws
 
 
 def _store_out(o_ref, acc_ref, xs_ref, ws_ref):
@@ -68,12 +109,19 @@ def _store_out(o_ref, acc_ref, xs_ref, ws_ref):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
     else:
         out = acc_ref[...].astype(jnp.float32)
-        out = out * xs_ref[0] * ws_ref[...][None, :]
+        out = out * xs_ref[0, 0] * ws_ref[...]
         o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _kernel(x_ref, p_ref, mask_ref, xs_ref, ws_ref, o_ref, acc_ref, *, n_k: int,
-            n_bits: int, signed: bool = True):
+def _mask_live(mask_ref, b: int, n_k: int, n_n: int):
+    """Zero-plane skip predicate: the flat [n_bits, n_k, n_n] SMEM mask
+    at (plane b, this grid step's K and N block)."""
+    k, n = pl.program_id(2), pl.program_id(1)
+    return mask_ref[(b * n_k + k) * n_n + n] != 0
+
+
+def _kernel(mask_ref, x_ref, p_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
+            n_k: int, n_n: int, n_bits: int, signed: bool):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -81,24 +129,31 @@ def _kernel(x_ref, p_ref, mask_ref, xs_ref, ws_ref, o_ref, acc_ref, *, n_k: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pw = _plane_w(n_bits, signed)
+    if signed:  # int8 activations straight onto the MXU
+        x, op_t, acc_t = x_ref[...], jnp.int8, jnp.int32
+    else:  # 0..255 activations: exact as bf16 (8 significant bits)
+        x = x_ref[...].astype(jnp.int32).astype(jnp.float32).astype(
+            jnp.bfloat16)
+        op_t, acc_t = jnp.bfloat16, jnp.float32
     packed = p_ref[...].astype(jnp.int32)  # (bk, bn) bytes: all planes
     for b in range(n_bits):  # bit-serial: one plane per MXU pass
-        @pl.when(mask_ref[b, 0, 0] != 0)  # zero-plane skip (beyond-paper)
+        @pl.when(_mask_live(mask_ref, b, n_k, n_n))  # zero-plane skip
         def _plane(b=b):
             plane = (packed >> b) & 1  # in-kernel unpack: shift+mask
-            part = jnp.dot(
-                x_ref[...].astype(jnp.int32), plane,
-                preferred_element_type=jnp.int32,
-            )
-            acc_ref[...] += pw[b] * part
+            if signed:
+                plane = plane.astype(op_t)
+            else:
+                plane = plane.astype(jnp.float32).astype(op_t)
+            part = jnp.dot(x, plane, preferred_element_type=acc_t)
+            acc_ref[...] += pw[b] * part.astype(jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         _store_out(o_ref, acc_ref, xs_ref, ws_ref)
 
 
-def _kernel_a4(x_ref, p_ref, mask_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
-               n_k: int, n_bits: int, signed: bool = True):
+def _kernel_a4(mask_ref, x_ref, we_ref, wo_ref, xs_ref, ws_ref, o_ref,
+               acc_ref, *, n_k: int, n_n: int, n_bits: int, signed: bool):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -113,15 +168,15 @@ def _kernel_a4(x_ref, p_ref, mask_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
     else:
         xe = xb & 0xF  # unsigned nibbles: plain shift+mask unpack
         xo = xb >> 4
-    packed = p_ref[...].astype(jnp.int32)  # (2*bk2, bn) bytes: all planes
-    we = packed[0::2]  # even K rows pair with the low nibbles
-    wo = packed[1::2]
+    xe, xo = xe.astype(jnp.int8), xo.astype(jnp.int8)  # both fit int8
+    we = we_ref[...].astype(jnp.int32)  # even K rows pair with low nibbles
+    wo = wo_ref[...].astype(jnp.int32)
     for b in range(n_bits):  # bit-serial: two half-K MXU passes per plane
-        @pl.when(mask_ref[b, 0, 0] != 0)  # zero-plane skip (beyond-paper)
+        @pl.when(_mask_live(mask_ref, b, n_k, n_n))  # zero-plane skip
         def _plane(b=b):
-            part = jnp.dot(xe, (we >> b) & 1,
+            part = jnp.dot(xe, ((we >> b) & 1).astype(jnp.int8),
                            preferred_element_type=jnp.int32)
-            part += jnp.dot(xo, (wo >> b) & 1,
+            part += jnp.dot(xo, ((wo >> b) & 1).astype(jnp.int8),
                             preferred_element_type=jnp.int32)
             acc_ref[...] += pw[b] * part
 
@@ -141,23 +196,53 @@ def plane_block_mask(planes: jax.Array, bk: int, bn: int) -> jax.Array:
     return (p.sum(axis=(2, 4)) > 0).astype(jnp.int8)
 
 
+def _grid_call(kernel, *, grid, in_blocks, bm, bn, out_shape, interpret):
+    """pallas_call over (m, n, k) with the flat plane mask scalar-
+    prefetched into SMEM and the scales as (1, 1) SMEM / (1, bn) rows."""
+    in_specs = [pl.BlockSpec(shape, imap) for shape, imap in in_blocks]
+    in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM),
+                 pl.BlockSpec((1, bn), lambda m, n, k, mask: (0, n))]
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((bm, bn), lambda m, n, k, mask: (m, n)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)]),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )
+
+
+def _flat_mask(plane_mask, packed, n_bits: int, bk: int, bn: int,
+               expect: tuple) -> jax.Array:
+    if plane_mask is None:
+        plane_mask = plane_block_mask(unpack_bitplanes_bytes(packed, n_bits),
+                                      bk, bn)
+    assert plane_mask.shape == expect, (plane_mask.shape, expect)
+    return plane_mask.astype(jnp.int32).reshape(-1)
+
+
 @functools.partial(
     jax.jit, static_argnames=("n_bits", "bm", "bn", "bk", "out_dtype",
                               "interpret", "signed")
 )
 def bitserial_matmul(
-    x_q: jax.Array,  # [M, K] int8 activations
+    x_q: jax.Array,  # [M, K] int8 (signed) or uint8 (unsigned) activations
     planes: jax.Array,  # [K, N] uint8 byte-packed, or [n_bits, K, N] {0,1}
     x_scale: jax.Array,  # scalar f32
     w_scale: jax.Array,  # [N] f32
-    plane_mask: jax.Array | None = None,  # [n_bits, K/bk, N/bn] int8
+    plane_mask: jax.Array | None = None,  # [n_bits, Kp/bk, Np/bn] int8
     *,
+    interpret: bool,
     n_bits: int | None = None,
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bk: int = DEFAULT_BK,
     out_dtype=jnp.float32,
-    interpret: bool = True,
     signed: bool = True,  # False: unsigned planes (MSB weight +2^(n-1))
 ) -> jax.Array:
     if planes.ndim == 3:  # legacy unpacked planes: re-pack to bytes
@@ -172,43 +257,23 @@ def bitserial_matmul(
     K, N = packed.shape
     M = x_q.shape[0]
     assert x_q.shape[1] == K
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
+    (bm, bn, bk), (Mp, Np, Kp) = _blocks(M, N, K, bm, bn, bk)
+    assert signed or bk * 255 < _BF16_EXACT, bk
+    x_q = _pad2(x_q.astype(jnp.int8 if signed else jnp.uint8), Mp, Kp)
+    packed = _pad2(packed, Kp, Np)
+    xs, ws = _scales(x_scale, w_scale, N, Np)
+    n_k, n_n = Kp // bk, Np // bn
+    mask = _flat_mask(plane_mask, packed, n_bits, bk, bn, (n_bits, n_k, n_n))
 
-    pad_m, pad_n, pad_k = (-M) % bm, (-N) % bn, (-K) % bk
-    if pad_m or pad_k:
-        x_q = jnp.pad(x_q, ((0, pad_m), (0, pad_k)))
-    if pad_k or pad_n:
-        packed = jnp.pad(packed, ((0, pad_k), (0, pad_n)))
-    w_scale = jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32), (N,))
-    if pad_n:
-        w_scale = jnp.pad(w_scale, (0, pad_n))
-    x_scale = jnp.reshape(jnp.asarray(x_scale, jnp.float32), (1,))
-
-    Mp, Kp = x_q.shape
-    Np = packed.shape[1]
-    n_k = Kp // bk
-    grid = (Mp // bm, Np // bn, n_k)
-    if plane_mask is not None:
-        assert plane_mask.shape == (n_bits, Kp // bk, Np // bn), plane_mask.shape
-        mask = plane_mask
-    else:
-        mask = plane_block_mask(unpack_bitplanes_bytes(packed, n_bits), bk, bn)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, n_k=n_k, n_bits=n_bits, signed=signed),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),
-            pl.BlockSpec((bk, bn), lambda m, n, k: (k, n)),
-            pl.BlockSpec((n_bits, 1, 1), lambda m, n, k: (0, k, n)),
-            pl.BlockSpec((1,), lambda m, n, k: (0,)),
-            pl.BlockSpec((bn,), lambda m, n, k: (n,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+    out = _grid_call(
+        functools.partial(_kernel, n_k=n_k, n_n=n_n, n_bits=n_bits,
+                          signed=signed),
+        grid=(Mp // bm, n_n, n_k),
+        in_blocks=[((bm, bk), lambda m, n, k, mask: (m, k)),
+                   ((bk, bn), lambda m, n, k, mask: (k, n))],
+        bm=bm, bn=bn, out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
-    )(x_q, packed, mask, x_scale, w_scale)
+    )(mask, x_q, packed, xs, ws)
     return out[:M, :N]
 
 
@@ -221,14 +286,14 @@ def bitserial_matmul_a4(
     planes: jax.Array,  # [K, N] uint8 byte-packed weight planes
     x_scale: jax.Array,  # scalar f32
     w_scale: jax.Array,  # [N] f32
-    plane_mask: jax.Array | None = None,  # [n_bits, K/(2*bk2), N/bn] int8
+    plane_mask: jax.Array | None = None,  # [n_bits, K2p/bk2, Np/bn] int8
     *,
+    interpret: bool,
     n_bits: int = 4,
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bk2: int = DEFAULT_BK // 2,
     out_dtype=jnp.float32,
-    interpret: bool = True,
     signed: bool = True,  # False: unsigned nibbles + unsigned plane weights
 ) -> jax.Array:
     """W4A4 bit-serial GEMM with byte-packed *activations* and weights.
@@ -237,48 +302,30 @@ def bitserial_matmul_a4(
     ``planes`` from ref.pack_bitplanes_bytes.  Each of the ``n_bits`` weight
     planes costs two MXU passes over half of K (even/odd nibble streams),
     so FLOPs scale with the plane count while both operand tiles move half
-    the VMEM bytes of the W8A8 byte-packed kernel.
+    the VMEM bytes of the W8A8 byte-packed kernel.  The even/odd weight
+    rows are split outside the kernel (a strided in-kernel slice does not
+    lower on the TPU), so each stream is its own (bk2, bn) block.
     """
     M, K2 = x_packed.shape
     K, N = planes.shape
     if K < 2 * K2:  # odd-K weights: pad the dangling row (nibble is zero)
         planes = jnp.pad(planes, ((0, 2 * K2 - K), (0, 0)))
-    bm, bn, bk2 = min(bm, M), min(bn, N), min(bk2, K2)
+    (bm, bn, bk2), (Mp, Np, K2p) = _blocks(M, N, K2, bm, bn, bk2)
+    x_packed = _pad2(x_packed, Mp, K2p)
+    planes = _pad2(planes.astype(jnp.uint8), 2 * K2p, Np)
+    xs, ws = _scales(x_scale, w_scale, N, Np)
+    n_k, n_n = K2p // bk2, Np // bn
+    mask = _flat_mask(plane_mask, planes, n_bits, 2 * bk2, bn,
+                      (n_bits, n_k, n_n))
 
-    pad_m, pad_n, pad_k2 = (-M) % bm, (-N) % bn, (-K2) % bk2
-    if pad_m or pad_k2:
-        x_packed = jnp.pad(x_packed, ((0, pad_m), (0, pad_k2)))
-    if pad_k2 or pad_n:
-        planes = jnp.pad(planes, ((0, 2 * pad_k2), (0, pad_n)))
-    w_scale = jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32), (N,))
-    if pad_n:
-        w_scale = jnp.pad(w_scale, (0, pad_n))
-    x_scale = jnp.reshape(jnp.asarray(x_scale, jnp.float32), (1,))
-
-    Mp, K2p = x_packed.shape
-    Np = planes.shape[1]
-    n_k = K2p // bk2
-    grid = (Mp // bm, Np // bn, n_k)
-    if plane_mask is not None:
-        assert plane_mask.shape == (n_bits, n_k, Np // bn), plane_mask.shape
-        mask = plane_mask
-    else:
-        mask = plane_block_mask(unpack_bitplanes_bytes(planes, n_bits),
-                                2 * bk2, bn)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel_a4, n_k=n_k, n_bits=n_bits, signed=signed),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk2), lambda m, n, k: (m, k)),
-            pl.BlockSpec((2 * bk2, bn), lambda m, n, k: (k, n)),
-            pl.BlockSpec((n_bits, 1, 1), lambda m, n, k: (0, k, n)),
-            pl.BlockSpec((1,), lambda m, n, k: (0,)),
-            pl.BlockSpec((bn,), lambda m, n, k: (n,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+    out = _grid_call(
+        functools.partial(_kernel_a4, n_k=n_k, n_n=n_n, n_bits=n_bits,
+                          signed=signed),
+        grid=(Mp // bm, n_n, n_k),
+        in_blocks=[((bm, bk2), lambda m, n, k, mask: (m, k)),
+                   ((bk2, bn), lambda m, n, k, mask: (k, n)),
+                   ((bk2, bn), lambda m, n, k, mask: (k, n))],
+        bm=bm, bn=bn, out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
-    )(x_packed, planes, mask, x_scale, w_scale)
+    )(mask, x_packed, planes[0::2], planes[1::2], xs, ws)
     return out[:M, :N]

@@ -32,18 +32,6 @@ DEFAULT_BK = 256
 NEG_INF = -1e30
 
 
-def _compiler_params(**kw):
-    """TPU compiler params across JAX releases (CompilerParams was renamed
-    from TPUCompilerParams); fail with a nameable error if both are gone."""
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        raise AttributeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams; unsupported JAX version")
-    return cls(**kw)
-
-
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
             *, n_k: int, bq: int, bk: int, causal: bool, scale: float):
     j = pl.program_id(2)
@@ -90,10 +78,10 @@ def flash_attention(
     k: jax.Array,  # [B, Hkv, Tk, D]
     v: jax.Array,
     *,
+    interpret: bool,
     causal: bool = True,
     bq: int = DEFAULT_BQ,
     bk: int = DEFAULT_BK,
-    interpret: bool = False,
 ) -> jax.Array:
     B, H, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
@@ -127,7 +115,7 @@ def flash_attention(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

@@ -1,9 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``use_pallas`` policy: on TPU backends the compiled kernels run natively;
-elsewhere (this CPU container) they run in interpret mode for correctness,
-and callers that are on the hot path (models, serving) use the XLA fallback
-(`*_xla`) which lowers to plain dot — numerically identical, fast on CPU.
+Platform policy, decided here and nowhere else: on a TPU the kernels run
+compiled; the Pallas interpreter runs only where the platform is the CPU
+(:func:`interpret_mode`).  Off the TPU, callers that are on the hot path
+(models, serving) use the XLA fallback (`*_xla`) which lowers to plain
+dot — numerically identical, fast on CPU.
 """
 from __future__ import annotations
 
@@ -19,6 +20,7 @@ from repro.kernels.quant_matmul import quant_matmul as _quant_pallas
 
 __all__ = [
     "on_tpu",
+    "interpret_mode",
     "quant_matmul",
     "bitserial_matmul",
     "bitserial_matmul_a4",
@@ -32,6 +34,11 @@ __all__ = [
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run through the interpreter: only on CPU."""
+    return jax.default_backend() == "cpu"
 
 
 def pack_weights(w_q: jax.Array, n_bits: int = 8) -> jax.Array:
@@ -49,7 +56,7 @@ def quant_matmul(x_q, w_q, x_scale, w_scale, bias=None, *, prefer_pallas: bool =
     """W8A8 GEMM with fused dequant epilogue."""
     if prefer_pallas or on_tpu():
         return _quant_pallas(x_q, w_q, x_scale, w_scale, bias,
-                             interpret=not on_tpu())
+                             interpret=interpret_mode())
     return ref.quant_matmul_ref(x_q, w_q, x_scale, w_scale, bias)
 
 
@@ -64,7 +71,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     fallback for the banded/cached paths)."""
     from repro.kernels.flash_attention import flash_attention as _fa
     if prefer_pallas or on_tpu():
-        return _fa(q, k, v, causal=causal, interpret=not on_tpu())
+        return _fa(q, k, v, causal=causal, interpret=interpret_mode())
     return ref.flash_attention_ref(q, k, v, causal=causal)
 
 
@@ -82,7 +89,7 @@ def bitserial_matmul_a4(x_packed, planes, x_scale, w_scale, *, k: int,
     ``k`` is the unpacked inner dimension."""
     if prefer_pallas or on_tpu():
         return _bitserial_a4_pallas(x_packed, planes, x_scale, w_scale,
-                                    n_bits=4, interpret=not on_tpu())
+                                    n_bits=4, interpret=interpret_mode())
     x_q = ref.unpack_activation_nibbles(x_packed, k)
     return ref.bitserial_matmul_ref(
         x_q, ref.unpack_bitplanes_bytes(planes, 4), x_scale, w_scale)
@@ -91,19 +98,17 @@ def bitserial_matmul_a4(x_packed, planes, x_scale, w_scale, *, k: int,
 def bitserial_matmul_exact(x_q, planes, *, n_bits: int,
                            w4a4: bool = False):
     """Exact unsigned-integer bit-serial GEMM through the Pallas kernel —
-    the backend-registry entry point (``core/backends.py``
-    ``pallas-interpret``).
+    the backend-registry entry point (``core/backends.py`` ``pallas``).
 
     Unsigned plane weights (MSB carries +2^(n-1), matching the packed
     word engine's operand convention), no dequant epilogue: the int32
     accumulator comes back verbatim (``out_dtype=int32`` skips the lossy
     float32 round-trip), so results are byte-comparable against the host
-    reference.  ``w4a4=True`` takes nibble-packed activations
-    (:func:`pack_activations`) through the half-K W4A4 kernel.  Runs the
-    Pallas interpreter off-TPU and the compiled kernel on TPU — real-TPU
-    lowering is this same entry with :func:`on_tpu` flipping
-    ``interpret`` off."""
-    interp = not on_tpu()
+    reference.  ``x_q`` holds unsigned activations below 256.
+    ``w4a4=True`` takes nibble-packed activations (:func:`pack_activations`)
+    through the half-K W4A4 kernel.  The compiled kernel on TPU, the
+    interpreter on CPU (:func:`interpret_mode`)."""
+    interp = interpret_mode()
     if w4a4:
         return _bitserial_a4_pallas(x_q, planes, 1.0, 1.0, n_bits=n_bits,
                                     out_dtype=jnp.int32, signed=False,
@@ -129,5 +134,5 @@ def bitserial_matmul(x_q, planes, x_scale, w_scale, *, n_bits: int | None = None
         unpacked = ref.unpack_bitplanes_bytes(planes, n_bits)
     if prefer_pallas or on_tpu():
         return _bitserial_pallas(x_q, planes, x_scale, w_scale, n_bits=n_bits,
-                                 interpret=not on_tpu())
+                                 interpret=interpret_mode())
     return ref.bitserial_matmul_ref(x_q, unpacked, x_scale, w_scale)

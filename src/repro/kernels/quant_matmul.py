@@ -9,7 +9,9 @@ never round-trips HBM.
 Grid: (M/bm, N/bn, K/bk), K innermost; int32 accumulator lives in a VMEM
 scratch tile, epilogue fires on the last K step.  Tile defaults keep the
 working set (x 128x512 + w 512x128 + acc 128x128x4B = 192 KB) well inside
-the ~16 MB/core VMEM while aligning both MXU dims to 128.
+the ~16 MB/core VMEM while aligning both MXU dims to 128.  Operands are
+zero-padded to the TPU tiling exactly as in kernels/bitserial_matmul.py;
+``interpret`` has no default (``kernels/ops.py`` decides it).
 """
 from __future__ import annotations
 
@@ -19,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.bitserial_matmul import _blocks, _pad2, _scales
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
@@ -32,17 +36,15 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, bias_ref, o_ref, acc_ref, *, n_k: int)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...].astype(jnp.int32),
-        w_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32,
-    )
+    # int8 x int8 -> int32 is the MXU's native integer form
+    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                            preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         out = acc_ref[...].astype(jnp.float32)
-        out = out * xs_ref[0] * ws_ref[...][None, :]
-        out = out + bias_ref[...][None, :]
+        out = out * xs_ref[0, 0] * ws_ref[...]
+        out = out + bias_ref[...]
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -56,32 +58,22 @@ def quant_matmul(
     w_scale: jax.Array,  # [N] f32 (per-channel)
     bias: jax.Array | None = None,  # [N] f32
     *,
+    interpret: bool,
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     bk: int = DEFAULT_BK,
     out_dtype=jnp.float32,
-    interpret: bool = True,
 ) -> jax.Array:
     M, K = x_q.shape
     K2, N = w_q.shape
     assert K == K2, (x_q.shape, w_q.shape)
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-
-    pad_m, pad_n, pad_k = (-M) % bm, (-N) % bn, (-K) % bk
-    if pad_m or pad_k:
-        x_q = jnp.pad(x_q, ((0, pad_m), (0, pad_k)))
-    if pad_k or pad_n:
-        w_q = jnp.pad(w_q, ((0, pad_k), (0, pad_n)))
+    (bm, bn, bk), (Mp, Np, Kp) = _blocks(M, N, K, bm, bn, bk)
+    x_q = _pad2(x_q.astype(jnp.int8), Mp, Kp)
+    w_q = _pad2(w_q.astype(jnp.int8), Kp, Np)
     if bias is None:
         bias = jnp.zeros((N,), jnp.float32)
-    w_scale = jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32), (N,))
-    if pad_n:
-        w_scale = jnp.pad(w_scale, (0, pad_n))
-        bias = jnp.pad(bias, (0, pad_n))
-    x_scale = jnp.reshape(jnp.asarray(x_scale, jnp.float32), (1,))
-
-    Mp, Kp = x_q.shape
-    Np = w_q.shape[1]
+    bias = _pad2(jnp.asarray(bias, jnp.float32).reshape(1, N), 1, Np)
+    xs, ws = _scales(x_scale, w_scale, N, Np)
     n_k = Kp // bk
     grid = (Mp // bm, Np // bn, n_k)
 
@@ -91,13 +83,15 @@ def quant_matmul(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),
             pl.BlockSpec((bk, bn), lambda m, n, k: (k, n)),
-            pl.BlockSpec((1,), lambda m, n, k: (0,)),
-            pl.BlockSpec((bn,), lambda m, n, k: (n,)),
-            pl.BlockSpec((bn,), lambda m, n, k: (n,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bn), lambda m, n, k: (0, n)),
+            pl.BlockSpec((1, bn), lambda m, n, k: (0, n)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x_q, w_q, x_scale, w_scale, bias)
+    )(x_q, w_q, xs, ws, bias)
     return out[:M, :N]

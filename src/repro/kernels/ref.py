@@ -98,8 +98,8 @@ def unpack_activation_nibbles(packed: jax.Array, K: int) -> jax.Array:
 
 def plane_weights(n_bits: int) -> jax.Array:
     """Per-plane scale: [1, 2, 4, ..., -2^(n-1)] (two's complement)."""
-    w = 2 ** jnp.arange(n_bits, dtype=jnp.int32)
-    return w.at[n_bits - 1].set(-(2 ** (n_bits - 1)))
+    w = [1 << b for b in range(n_bits - 1)] + [-(1 << (n_bits - 1))]
+    return jnp.asarray(w, jnp.int32)
 
 
 def bitserial_matmul_ref(

@@ -35,7 +35,7 @@ from repro.distributed.hlo_analysis import (CollectiveStats, collective_bytes,
                                              xla_cost_analysis)
 from repro.distributed.hlo_loop_analysis import analyze_hlo
 from repro.distributed.roofline import TPU_V5E, roofline
-from repro.launch.mesh import make_production_mesh, set_mesh_compat
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_jitted_step
 
 
@@ -51,7 +51,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     bundle = build_jitted_step(cfg, spec, mesh)
     # set_mesh (not `with mesh:`) — activation sharding constraints inside
     # the model read the abstract-mesh context at trace time.
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         lowered = bundle.step.lower(*bundle.example_args)
         compiled = lowered.compile()
     t_compile = time.time() - t0

@@ -8,46 +8,25 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh", "make_mesh_compat",
-           "set_mesh_compat", "mesh_axes"]
+__all__ = ["make_production_mesh", "make_local_mesh", "mesh_axes"]
 
 
-def set_mesh_compat(mesh):
-    """Context manager installing ``mesh`` for trace-time sharding-constraint
-    resolution: ``jax.set_mesh`` where it exists, the legacy ``with mesh:``
-    (Mesh is itself a context manager) on older JAX."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with explicit-Auto axis types where the installed
-    JAX supports them (``jax.sharding.AxisType`` and the ``axis_types``
-    kwarg were added/renamed across releases; Auto is the default when the
-    kwarg is absent, so omitting it is behavior-preserving)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int | None = None, model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU training)."""
     n = len(jax.devices())
     data = data or (n // model)
-    return make_mesh_compat((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def mesh_axes(mesh) -> tuple[str, ...]:

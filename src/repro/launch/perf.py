@@ -26,7 +26,7 @@ from repro.configs.base import SHAPES
 from repro.distributed.hlo_loop_analysis import analyze_hlo
 from repro.distributed.roofline import TPU_V5E, roofline
 from repro.distributed.hlo_analysis import CollectiveStats
-from repro.launch.mesh import make_production_mesh, set_mesh_compat
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import VARIANTS, build_jitted_step
 
 
@@ -37,7 +37,7 @@ def run_variant(arch: str, shape_name: str, variant: str,
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
     bundle = build_jitted_step(cfg, spec, mesh, variant=variant)
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         compiled = bundle.step.lower(*bundle.example_args).compile()
     mem = compiled.memory_analysis()
     la = analyze_hlo(compiled.as_text())

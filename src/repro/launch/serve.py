@@ -52,6 +52,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import REGISTRY, get_config, reduced_config
+from repro.kernels import ops
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.engine_api import Engine as _EngineAPI
 from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as T
@@ -286,10 +288,10 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
     latency model prices the checksum passes.  Independent of the flag, a
     batch whose forward RAISES walks the recovery ladder (``_recover``):
     primary-schedule retries within the oldest request's remaining
-    deadline budget, then a dense/no-overlap fallback schedule, then the
-    float reference forward, then the batch is marked failed — the engine
-    never strands queued requests.  Only primary successes (retries
-    included, at their true total wall time) calibrate the
+    deadline budget, then a dense/no-overlap fallback schedule, then (off
+    a TPU) the float reference forward, then the batch is marked failed —
+    the engine never strands queued requests.  Only primary successes
+    (retries included, at their true total wall time) calibrate the
     :class:`~repro.core.slo.LatencyModel`; degraded batches are
     explicitly excluded (``LatencyModel.exclude``).
 
@@ -531,9 +533,8 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
         2. Dense/no-overlap fallback schedule — plan-shape trouble
            (quarantine storms, overlap/sparsity interactions) recovers
            here; the batch is excluded from calibration.
-        3. Float reference forward — always numerically available; the
-           result is no longer the emulation's logits, but the request is
-           answered.
+        3. Float reference forward (off a TPU only) — the result is no
+           longer the emulation's logits, but the request is answered.
         4. Mark the batch failed (``stats()['errors']`` records why) and
            keep draining.
 
@@ -561,14 +562,17 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
             return logits, report, "fallback-schedule"
         except Exception as e:  # noqa: BLE001
             last = e
-        # rung 3: float reference — answers the request outside the emulation
-        try:
-            logits = np.asarray(self._inception.apply(
-                self.params, jnp.asarray(x, jnp.float32), quant=False,
-                config=self.config))
-            return logits, None, "float"
-        except Exception as e:  # noqa: BLE001
-            last = e
+        # rung 3: float reference — answers the request outside the
+        # emulation.  Not on a TPU: there a device failure must fail the
+        # batch, never come back as a float answer
+        if not ops.on_tpu():
+            try:
+                logits = np.asarray(self._inception.apply(
+                    self.params, jnp.asarray(x, jnp.float32), quant=False,
+                    config=self.config))
+                return logits, None, "float"
+            except Exception as e:  # noqa: BLE001
+                last = e
         # rung 4: unreclaimable
         self._fail_requests(batch, last)
         return None, None, None
@@ -734,6 +738,7 @@ def main() -> int:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-tokens", type=int, default=16)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.neural_cache:
         return _main_neural_cache(args)

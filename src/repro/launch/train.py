@@ -38,7 +38,7 @@ from repro.data import DataIterator, SyntheticLMDataset
 from repro.distributed.sharding import (
     make_batch_sharding, make_param_shardings, ShardingReport)
 from repro.launch import steps as S
-from repro.launch.mesh import make_local_mesh, set_mesh_compat
+from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as T
 
 
@@ -84,7 +84,7 @@ def train(cfg, shape: ShapeSpec, *, steps: int, ckpt_dir: str | None,
                                  shape.global_batch, seed=seed)
     it = DataIterator(dataset, tok_sh)
 
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         params = T.init_lm(cfg, jax.random.key(seed))
         params = jax.device_put(
             params, make_param_shardings(cfg, mesh, params))

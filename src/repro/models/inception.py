@@ -857,8 +857,10 @@ def nc_forward(params: dict, x: jax.Array,
     ``engine`` names a registered backend (``core/backends.py``).
     ``engine=None`` resolves by the standing precedence: the schedule's
     ``backend`` pin (``plan_network(..., backend=...)``) > the
-    ``NC_BACKEND`` environment variable > the bucketed-jit engine once
-    the compilation cache amortizes (batch >= 2), else the host engine.
+    ``NC_BACKEND`` environment variable > the compiled Pallas kernels
+    (``pallas``) where the platform is a TPU > the bucketed-jit engine
+    once the compilation cache amortizes (batch >= 2), else the host
+    engine.
     An explicit engine that contradicts a backend-carrying schedule
     raises (the schedule already decided).
     ``schedule`` accepts a precomputed :class:`NetworkSchedule` (the
@@ -927,13 +929,9 @@ def nc_forward(params: dict, x: jax.Array,
                          "(plan_network(..., backend=...)); engine= "
                          "contradicting a backend-carrying schedule is "
                          "ambiguous")
-    if engine is None:
-        if schedule is not None and schedule.backend is not None:
-            engine = schedule.backend
-        else:
-            engine = _backends.env_backend() or ("jit" if B >= 2 else "host")
-    else:
-        engine = _backends.get_backend(engine).name
+    engine = _backends.resolve_backend(
+        engine, schedule.backend if schedule is not None else None,
+        default="jit" if B >= 2 else "host")
     specs_list = inception_v3_specs(config)
     specs = {s.name: s for s in specs_list}
     if wpack is None:

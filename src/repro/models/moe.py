@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import current_abstract_mesh
 from repro.models.layers import dense_init
 
 __all__ = ["moe_init", "moe_apply"]
@@ -25,8 +24,8 @@ def _ep_axes(cfg: ModelConfig):
     ride 'model'.  None when unconstrained (tests, single device)."""
     if cfg.act_spec is None:
         return None, None
-    mesh = current_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return None, None
     b = cfg.act_spec[0]
     flat = b if isinstance(b, tuple) else ((b,) if b else ())

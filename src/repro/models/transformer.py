@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import current_abstract_mesh
 from repro.models import layers as L
 from repro.models import mamba2 as M
 from repro.models import moe as MoE
@@ -75,8 +74,8 @@ def _constrain(cfg: ModelConfig, x, kind: str = "act"):
     """
     if cfg.act_spec is None:
         return x
-    mesh = current_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     b, s, v = cfg.act_spec
     if kind == "act":  # [B, T, d]
@@ -185,8 +184,8 @@ def _sp_enter(cfg, h):
     b, s, _ = cfg.act_spec
     if s is None:
         return h
-    mesh = current_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return h
     return jax.lax.with_sharding_constraint(
         h, jax.sharding.PartitionSpec(b, None, None))
@@ -306,8 +305,8 @@ def lm_apply(cfg, params, tokens=None, *, embeds=None, positions=None,
 def lm_logits(cfg, params, hidden):
     out = _head(cfg, params, hidden)
     if cfg.act_spec is not None and out.ndim == 2:
-        mesh = current_abstract_mesh()
-        if mesh is not None and mesh.axis_names:
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.axis_names:
             b, _, v = cfg.act_spec
             out = jax.lax.with_sharding_constraint(
                 out, jax.sharding.PartitionSpec(b, v))

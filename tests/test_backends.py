@@ -27,7 +27,7 @@ differentially:
   once per (planes, acc, K) bucket even when the same shapes flow
   through DIFFERENT layers (``engine_cache_info`` reporting matches).
 
-Tier-1 runs the host+jit conformance; the ``pallas-interpret``
+Tier-1 runs the host+jit conformance; the ``pallas``
 parametrizations carry the ``backends`` marker (the interpreter is slow)
 and run under benchmarks/run.py's gate or
 ``pytest -m backends -o addopts=``.
@@ -48,7 +48,7 @@ GEOM = XEON_E5_35MB
 # host and jit conformance is tier-1; the interpret-mode sweep runs under
 # the `backends` marker (satellite: pytest.ini addopts excludes it)
 BACKENDS = ["host", "jit",
-            pytest.param("pallas-interpret", marks=pytest.mark.backends)]
+            pytest.param("pallas", marks=pytest.mark.backends)]
 
 
 def _quantized_conv_case(seed, *, bits=8, M=6, C=3, R=3, prune=0.0,
@@ -175,10 +175,10 @@ def test_pallas_interpret_dot_smoke():
                                           engine="host")
     backends.dispatch_stats_clear()
     vals, cycles = bs.packed_dot_words(xw, ww, K=K, acc_bits=32,
-                                       engine="pallas-interpret")
+                                       engine="pallas")
     np.testing.assert_array_equal(np.asarray(vals), np.asarray(ref))
     assert cycles == ref_cycles
-    assert backends.dispatch_stats()["pallas-interpret"]["native"] == 1
+    assert backends.dispatch_stats()["pallas"]["native"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -317,7 +317,7 @@ def test_jit_compile_cache_one_entry_per_bucket():
     assert info["entries"] == 1  # the FC reused the conv's bucket
     assert info["keys"] == [(8, 8, 32, 144)]
     # identical padded operand shapes -> the SAME executable served both
-    # layers (``compiled`` is best-effort: 0 if jax hides _cache_size)
+    # layers
     assert info["compiled"] == compiled_after_conv
 
     # the host backend never touches the compile cache
@@ -348,11 +348,11 @@ def test_engine_cache_distinct_buckets():
 # ---------------------------------------------------------------------------
 def test_registry_capability_flags():
     assert backends.registered_backends() == ("host", "jit",
-                                              "pallas-interpret")
+                                              "pallas")
     host = backends.get_backend("host")
     assert host.acc_bits is None and host.supports_acc(24)
     assert host.max_lane_words is None
-    pal = backends.get_backend("pallas-interpret")
+    pal = backends.get_backend("pallas")
     assert pal.supports_acc(32) and pal.supports_acc(24)
     assert not pal.supports_acc(16)
     assert pal.w4a4 and pal.compressed_planes and pal.integrity
@@ -371,10 +371,43 @@ def test_dispatch_stats_count_fallbacks():
     ww = nc._pack_w_rows(rng.integers(0, 256, size=(2, 9)), 8)
     ref, _ = bs.packed_dot_words(xw, ww, K=9, acc_bits=32, engine="host")
     vals, _ = bs.packed_dot_words(xw, ww, K=9, acc_bits=32,
-                                  engine="pallas-interpret")
+                                  engine="pallas")
     np.testing.assert_array_equal(np.asarray(vals), np.asarray(ref))
-    st = backends.dispatch_stats()["pallas-interpret"]
+    st = backends.dispatch_stats()["pallas"]
     assert st == {"native": 0, "fallback": 1}
+
+
+def test_pallas_on_tpu_raises_instead_of_falling_back(monkeypatch):
+    """On a TPU an input outside the Pallas envelope raises: the device
+    path never answers from the host (the platform check is steered)."""
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(14)
+    xw = nc._pack_x_rows(rng.integers(0, 256, size=(3, 9)), 8)
+    ww = nc._pack_w_rows(rng.integers(0, 256, size=(2, 9)), 8)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    backends.dispatch_stats_clear()
+    with pytest.raises(ValueError, match="rows share words"):
+        bs.packed_dot_words(xw, ww, K=9, acc_bits=32, engine="pallas")
+    assert backends.dispatch_stats()["pallas"] == {"native": 0,
+                                                   "fallback": 0}
+
+
+def test_tpu_platform_defaults_to_pallas(monkeypatch):
+    """On a TPU ``pallas`` is the default below explicit engine, plan pin
+    and NC_BACKEND; on CPU each caller keeps its own default."""
+    from repro.kernels import ops
+
+    monkeypatch.delenv(backends.ENV_VAR, raising=False)
+    assert backends.resolve_backend(default="jit") == "jit"
+    assert backends.default_backend() == "host"
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert backends.default_backend() == "pallas"
+    assert backends.resolve_backend(default="jit") == "pallas"
+    assert backends.resolve_backend("host") == "host"
+    assert backends.resolve_backend(None, "jit") == "jit"
+    monkeypatch.setenv(backends.ENV_VAR, "jit")
+    assert backends.resolve_backend() == "jit"
 
 
 # ---------------------------------------------------------------------------
@@ -416,7 +449,7 @@ def test_serving_engine_backend_validation_and_switch():
 @pytest.mark.slow
 @pytest.mark.backends
 def test_nc_forward_pallas_interpret_end_to_end():
-    """One reduced-Inception forward routed through ``pallas-interpret``
+    """One reduced-Inception forward routed through ``pallas``
     as a pure config change (``plan_network(backend=...)``): logits and
     modeled cycles byte-identical to the host run, with the dispatch
     ledger showing the Pallas kernel natively served the large-K layers
@@ -436,15 +469,15 @@ def test_nc_forward_pallas_interpret_end_to_end():
                                            engine="host")
     specs = inception.inception_v3_specs(cfg)
     schedule = sched.plan_network(specs, GEOM, batch=1,
-                                  backend="pallas-interpret")
-    assert schedule.backend == "pallas-interpret"
+                                  backend="pallas")
+    assert schedule.backend == "pallas"
     backends.dispatch_stats_clear()
     out, report = inception.nc_forward(params, x, config=cfg,
                                        schedule=schedule)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     assert report.total_emulated_cycles == ref_report.total_emulated_cycles
     assert report.total_modeled_cycles == ref_report.total_modeled_cycles
-    st = backends.dispatch_stats()["pallas-interpret"]
+    st = backends.dispatch_stats()["pallas"]
     assert st["native"] > 0
 
     # contradicting the schedule's pin raises (the plan already decided)

@@ -22,8 +22,8 @@ _RESTORE = textwrap.dedent("""
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint import restore_checkpoint
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     like = {"w": jnp.zeros((8, 8)), "b": jnp.zeros((8,))}
     sh = {"params": {"w": NamedSharding(mesh, P("data", "model")),
                      "b": NamedSharding(mesh, P("model"))}}

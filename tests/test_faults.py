@@ -496,6 +496,27 @@ def test_nc_engine_float_reference_rung(tiny):
     assert eng.degraded_batches == 1
 
 
+def test_nc_engine_no_float_rung_on_tpu(tiny, monkeypatch):
+    """On a TPU the ladder has no float rung: a failure on the device
+    fails the batch with its error recorded, instead of answering from
+    the float reference path."""
+    from repro.kernels import ops
+
+    cfg, params = tiny
+    eng = _nc_engine(cfg, params, max_batch=2)
+    _submit_images(eng, cfg, 2)
+
+    def broken(x, schedule):
+        raise RuntimeError("device down")
+
+    eng._forward = broken
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    done = eng.run()
+    assert done == [] and not eng.queue
+    assert all(r.failed and r.error == "device down" for r in eng.failed)
+    assert len(eng.failed) == 2 and eng.degraded_batches == 0
+
+
 def test_nc_engine_unreclaimable_batch_fails_and_drains(tiny):
     """Rung 4: the whole ladder fails — the batch is marked failed with
     the error recorded, and the engine drains the remaining queue

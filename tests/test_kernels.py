@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import quantize as q
 from repro.kernels import ref
@@ -233,14 +233,14 @@ def test_a4_hlo_flops_scale_with_planes():
     w8 = ref.pack_bitplanes_bytes(
         jnp.asarray(rng.integers(-128, 128, size=(K, N)).astype(np.int8)), 8)
     f8 = jax.jit(lambda a, p: bitserial_matmul(a, p, 1.0, jnp.ones(N),
-                                               n_bits=8))
+                                               n_bits=8, interpret=True))
     fl8 = xla_cost_analysis(f8.lower(x8, w8).compile()).get("flops", 0)
     x4 = ref.pack_activation_nibbles(
         jnp.asarray(rng.integers(-8, 8, size=(M, K)).astype(np.int8)))
     w4 = ref.pack_bitplanes_bytes(
         jnp.asarray(rng.integers(-8, 8, size=(K, N)).astype(np.int8)), 4)
     f4 = jax.jit(lambda a, p: bitserial_matmul_a4(a, p, 1.0, jnp.ones(N),
-                                                  n_bits=4))
+                                                  n_bits=4, interpret=True))
     fl4 = xla_cost_analysis(f4.lower(x4, w4).compile()).get("flops", 0)
     assert fl8 > 0 and fl4 > 0
     assert 0.35 < fl4 / fl8 < 0.65, (fl4, fl8)

@@ -19,10 +19,10 @@ _SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.distributed.pipeline import gpipe_apply, split_stages, bubble_fraction
-    from repro.launch.mesh import make_mesh_compat
 
     S, L, M, MB, D = 4, 8, 6, 2, 16
-    mesh = make_mesh_compat((S,), ("stage",))
+    mesh = jax.make_mesh((S,), ("stage",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     k = jax.random.key(0)
     Ws = jax.random.normal(k, (L, D, D), jnp.float32) / jnp.sqrt(D)
     x = jax.random.normal(jax.random.key(1), (M, MB, D), jnp.float32)
