@@ -22,7 +22,9 @@ Contract
   delegate inputs outside its native envelope (capability flags below)
   to the host body on CPU — delegation is counted in
   :func:`dispatch_stats` so tests can assert the native path actually
-  ran.
+  ran.  The same counters hold the bytes each backend hands to the
+  device and copies back (``pallas``: the padded word grids and the
+  padded int32 result).
 * **Selection is configuration.**  Precedence at every call site:
   explicit ``engine=`` argument > the plan's ``backend`` field
   (``schedule.plan_layer(backend=...)`` — the same plan-decision idiom
@@ -69,6 +71,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import bitserial as bs
 from repro.kernels import ops
@@ -117,13 +120,15 @@ class Backend:
 
 
 _REGISTRY: dict[str, Backend] = {}
-# per-backend dispatch counters: name -> [native, fallback-to-host]
+# per-backend dispatch counters, in the order of _DISPATCH_KEYS
+_DISPATCH_KEYS = ("native", "fallback", "bytes_to_device",
+                  "bytes_from_device")
 _DISPATCH: dict[str, list[int]] = {}
 
 
 def register_backend(backend: Backend) -> Backend:
     _REGISTRY[backend.name] = backend
-    _DISPATCH.setdefault(backend.name, [0, 0])
+    _DISPATCH.setdefault(backend.name, [0] * len(_DISPATCH_KEYS))
     return backend
 
 
@@ -177,19 +182,27 @@ def resolve_backend(explicit: str | None = None,
 
 def dispatch_stats() -> dict[str, dict[str, int]]:
     """Per-backend dispatch counters since the last clear:
-    ``{name: {"native": n, "fallback": m}}`` — ``fallback`` counts calls
-    delegated to the host body (inputs outside the native envelope)."""
-    return {name: {"native": c[0], "fallback": c[1]}
+    ``{name: {"native": n, "fallback": m, "bytes_to_device": a,
+    "bytes_from_device": b}}`` — ``fallback`` counts calls delegated to
+    the host body (inputs outside the native envelope); the byte counts
+    are the operands handed to the device and the results copied back,
+    padding included (``pallas`` only: the other bodies run on the
+    host or keep their transfers inside XLA)."""
+    return {name: dict(zip(_DISPATCH_KEYS, c))
             for name, c in _DISPATCH.items()}
 
 
 def dispatch_stats_clear() -> None:
     for c in _DISPATCH.values():
-        c[0] = c[1] = 0
+        c[:] = [0] * len(c)
 
 
-def _note(name: str, native: bool) -> None:
-    _DISPATCH[name][0 if native else 1] += 1
+def _note(name: str, native: bool, to_device: int = 0,
+          from_device: int = 0) -> None:
+    c = _DISPATCH[name]
+    c[0 if native else 1] += 1
+    c[2] += to_device
+    c[3] += from_device
 
 
 # ---------------------------------------------------------------------------
@@ -293,10 +306,26 @@ def _pallas_dot_words(xw, ww, *, K: int, acc_bits: int,
     the bucketed-jit engine's buckets, decode and run the byte-packed
     Pallas kernel in one device program (the W4A4 nibble kernel when
     both operands fit 4 planes), and scatter the exact int32 accumulator
-    back into the broadcast grid."""
+    back into the broadcast grid.
+
+    Profiler spans, one each per call: ``nc.pallas.launch`` (envelope
+    check, flatten, pad, enqueue), ``nc.pallas.wait`` (the host blocked
+    on the device run and the copy back) and ``nc.pallas.scatter``
+    (slice, widen to int64, scatter)."""
     backend = _REGISTRY["pallas"]
-    reason = _pallas_fallback_reason(xw, ww, K=K, acc_bits=acc_bits,
-                                     backend=backend)
+    with TraceAnnotation("nc.pallas.launch"):
+        reason = _pallas_fallback_reason(xw, ww, K=K, acc_bits=acc_bits,
+                                         backend=backend)
+        if reason is None:
+            nx, nw = int(xw.shape[0]), int(ww.shape[0])
+            gx, gw = xw.shape[1:-1], ww.shape[1:-1]
+            xf = np.asarray(xw, np.uint32).reshape(nx, -1, xw.shape[-1])
+            wf = np.asarray(ww, np.uint32).reshape(nw, -1, ww.shape[-1])
+            Rx, Rw = xf.shape[1], wf.shape[1]
+            w4a4 = backend.w4a4 and nx <= 4 and nw <= 4 and K >= 2
+            xf = _pad_rows(xf, bs.bucket_words(Rx))
+            wf = _pad_rows(wf, bs.bucket_words(Rw))
+            out = _pallas_exact(xf, wf, K=K, w4a4=w4a4)
     if reason is not None:
         if ops.on_tpu():
             raise ValueError(f"pallas backend: input outside the native "
@@ -304,26 +333,20 @@ def _pallas_dot_words(xw, ww, *, K: int, acc_bits: int,
                              f"host fallback")
         _note("pallas", native=False)
         return bs._dot_words_impl(xw, ww, K=K, acc_bits=acc_bits)
-    _note("pallas", native=True)
-
-    nx, nw = int(xw.shape[0]), int(ww.shape[0])
-    gx, gw = xw.shape[1:-1], ww.shape[1:-1]
-    xf = np.asarray(xw, np.uint32).reshape(nx, -1, xw.shape[-1])
-    wf = np.asarray(ww, np.uint32).reshape(nw, -1, ww.shape[-1])
-    Rx, Rw = xf.shape[1], wf.shape[1]
-    w4a4 = backend.w4a4 and nx <= 4 and nw <= 4 and K >= 2
-    out = _pallas_exact(_pad_rows(xf, bs.bucket_words(Rx)),
-                        _pad_rows(wf, bs.bucket_words(Rw)), K=K, w4a4=w4a4)
-    O = np.asarray(out, np.int64)[:Rx, :Rw]  # exact int32 accumulator
+    _note("pallas", native=True, to_device=xf.nbytes + wf.nbytes,
+          from_device=out.nbytes)
+    with TraceAnnotation("nc.pallas.wait"):
+        O = np.asarray(out)  # exact int32 accumulator, padded
 
     # scatter back into the broadcast grid: each grid axis is owned by at
     # most one operand (separability checked above), so interleaving the
     # (gx_i, gw_i) axis pairs and merging each pair (one side is 1)
     # reproduces np.broadcast_shapes(gx, gw)
-    n_axes = len(gx)
-    O = O.reshape(tuple(gx) + tuple(gw))
-    O = O.transpose([a for i in range(n_axes) for a in (i, n_axes + i)])
-    return O.reshape(np.broadcast_shapes(gx, gw))
+    with TraceAnnotation("nc.pallas.scatter"):
+        n_axes = len(gx)
+        O = O[:Rx, :Rw].astype(np.int64).reshape(tuple(gx) + tuple(gw))
+        O = O.transpose([a for i in range(n_axes) for a in (i, n_axes + i)])
+        return O.reshape(np.broadcast_shapes(gx, gw))
 
 
 register_backend(Backend(

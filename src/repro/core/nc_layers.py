@@ -57,6 +57,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import backends as _backends
 from repro.core import bitserial as bs
@@ -345,34 +346,50 @@ def nc_conv2d(
     (tests/test_sparsity.py's differential sweep).  Like sparsity,
     overlap and integrity, compression is a plan decision:
     ``compressed=True`` alongside an explicit plan raises.
+
+    Profiler spans (``jax.profiler.TraceAnnotation``, recorded only while
+    a profiler session runs; docs/SERVING.md lists them all):
+    ``nc.conv.im2col`` (quantize, pad, window extraction, lane casts,
+    occupancy validation), ``nc.conv.pack`` (the per-layer filter pack
+    and each miss of the per-tile window and filter caches),
+    ``nc.conv.store`` (each tile's values into the output),
+    ``nc.conv.epilogue`` (pruned-filter fill, zero-point correction, the
+    result array) and ``nc.accounting`` (the :class:`ConvStats` counts).
     """
-    xin = np.asarray(x)
-    batched = xin.ndim == 4
-    x4 = xin if batched else xin[None]
-    B = x4.shape[0]
-    x_qps = _as_qp_list(x_qp, B)
-    wq = (np.asarray(w, np.int64)
-          if np.issubdtype(np.asarray(w).dtype, np.integer)
-          else _quantize_np(np.asarray(w), w_qp))
-    xq = _quantize_images(x4, x_qps)
-    R, S, Cw, M = wq.shape
-    assert xq.shape[3] == Cw
-    zxs = np.array([int(p.zero_point) for p in x_qps], np.int64)
-    if padding == "SAME":
-        ph = _same_pad(xq.shape[1], R, stride)
-        pw = _same_pad(xq.shape[2], S, stride)
-        padded = np.empty((B, xq.shape[1] + sum(ph), xq.shape[2] + sum(pw),
-                           Cw), np.int64)
-        padded[:] = zxs[:, None, None, None]  # per-image zero point
-        padded[:, ph[0]:ph[0] + xq.shape[1], pw[0]:pw[0] + xq.shape[2]] = xq
-        xq = padded
-    elif padding != "VALID":
-        raise ValueError(f"padding must be VALID or SAME, got {padding!r}")
-    H = xq.shape[1]
-    win, E, F = _extract_windows_batch(xq, R, S, stride)  # (B, E, F, K)
-    K = R * S * Cw
-    n_bits = max(x_qps[0].bits, w_qp.bits)
-    acc_bits = 32
+    with TraceAnnotation("nc.conv.im2col"):
+        xin = np.asarray(x)
+        batched = xin.ndim == 4
+        x4 = xin if batched else xin[None]
+        B = x4.shape[0]
+        x_qps = _as_qp_list(x_qp, B)
+        wq = (np.asarray(w, np.int64)
+              if np.issubdtype(np.asarray(w).dtype, np.integer)
+              else _quantize_np(np.asarray(w), w_qp))
+        xq = _quantize_images(x4, x_qps)
+        R, S, Cw, M = wq.shape
+        assert xq.shape[3] == Cw
+        zxs = np.array([int(p.zero_point) for p in x_qps], np.int64)
+        if padding == "SAME":
+            ph = _same_pad(xq.shape[1], R, stride)
+            pw = _same_pad(xq.shape[2], S, stride)
+            padded = np.empty((B, xq.shape[1] + sum(ph),
+                               xq.shape[2] + sum(pw), Cw), np.int64)
+            padded[:] = zxs[:, None, None, None]  # per-image zero point
+            padded[:, ph[0]:ph[0] + xq.shape[1],
+                   pw[0]:pw[0] + xq.shape[2]] = xq
+            xq = padded
+        elif padding != "VALID":
+            raise ValueError(
+                f"padding must be VALID or SAME, got {padding!r}")
+        H = xq.shape[1]
+        win, E, F = _extract_windows_batch(xq, R, S, stride)  # (B, E, F, K)
+        K = R * S * Cw
+        n_bits = max(x_qps[0].bits, w_qp.bits)
+        acc_bits = 32
+        rows_total = B * E * F
+        lane_dtype = np.uint8 if n_bits <= 8 else np.uint32
+        win_flat = win.reshape(rows_total, K).astype(lane_dtype)
+        w_rows = wq.reshape(K, M).T.astype(lane_dtype)
 
     # scheduler contract: the plan carries the mapper layout (word-line
     # budget already enforced), the geometry-bounded tile sizes and the
@@ -380,10 +397,6 @@ def nc_conv2d(
     spec = layer_spec or LayerSpec(
         name="nc_conv2d", kind="conv", H=H, R=R, S=S, C=Cw, M=M, E=E,
         stride=stride)
-    rows_total = B * E * F
-    win_flat = win.reshape(rows_total, K).astype(np.uint8 if n_bits <= 8
-                                                 else np.uint32)
-    w_rows = wq.reshape(K, M).T.astype(np.uint8 if n_bits <= 8 else np.uint32)
     zw_int = int(w_qp.zero_point)
     replan = plan is None or tile_pixels is not None or tile_filters is not None
     if occupancy is not None and not replan:
@@ -441,23 +454,24 @@ def nc_conv2d(
     # sparse plans prune all-zero filters out of the engine's filter axis;
     # an over-claiming occupancy (marking a live filter zero) would corrupt
     # results, so it is validated against the actual quantized weights here
-    occ = plan.occupancy
-    if occ is not None and occ.zero_filters:
-        if occ.total_filters != M:
-            raise ValueError(f"{spec.name}: occupancy covers "
-                             f"{occ.total_filters} filters, layer has {M}")
-        zero_idx = np.asarray(occ.zero_filters, np.int64)
-        not_zero = ~(w_rows[zero_idx] == zw_int).all(axis=1)
-        if not_zero.any():
-            raise ValueError(
-                f"{spec.name}: occupancy marks filters "
-                f"{zero_idx[not_zero].tolist()} as zero but their weights "
-                f"are live (stale plan?)")
-        zero_mask = np.zeros(M, bool)
-        zero_mask[zero_idx] = True
-        live_idx = np.flatnonzero(~zero_mask)
-    else:
-        zero_mask = live_idx = None
+    with TraceAnnotation("nc.conv.im2col"):
+        occ = plan.occupancy
+        if occ is not None and occ.zero_filters:
+            if occ.total_filters != M:
+                raise ValueError(f"{spec.name}: occupancy covers "
+                                 f"{occ.total_filters} filters, layer has {M}")
+            zero_idx = np.asarray(occ.zero_filters, np.int64)
+            not_zero = ~(w_rows[zero_idx] == zw_int).all(axis=1)
+            if not_zero.any():
+                raise ValueError(
+                    f"{spec.name}: occupancy marks filters "
+                    f"{zero_idx[not_zero].tolist()} as zero but their weights "
+                    f"are live (stale plan?)")
+            zero_mask = np.zeros(M, bool)
+            zero_mask[zero_idx] = True
+            live_idx = np.flatnonzero(~zero_mask)
+        else:
+            zero_mask = live_idx = None
 
     w_rows_live = w_rows if live_idx is None else w_rows[live_idx]
     M_live = w_rows_live.shape[0]
@@ -470,12 +484,13 @@ def nc_conv2d(
     # instead of the dense grid; tiles reconstruct their column slice.
     ww_all = cw_all = None
     if M_live and not overlap_exec:
-        grid = _pack_w_rows(w_rows_live, w_qp.bits)
-        if compressed_exec:
-            cw_all = bs.CompressedPlanes.compress(grid)
-        else:
-            ww_all = grid
-        del grid
+        with TraceAnnotation("nc.conv.pack"):
+            grid = _pack_w_rows(w_rows_live, w_qp.bits)
+            if compressed_exec:
+                cw_all = bs.CompressedPlanes.compress(grid)
+            else:
+                ww_all = grid
+            del grid
     csr_bytes = [0, 0]  # measured (payload, index) bytes of the CSR store
     if cw_all is not None:
         csr_bytes = [cw_all.payload_bytes, cw_all.index_bytes]
@@ -502,44 +517,48 @@ def nc_conv2d(
         tile's columns pack exactly once per layer per batch)."""
         ww = w_cache.get(mi)
         if ww is None:
-            m0, m1 = m_tiles[mi]
-            if cw_all is not None:
-                ww = cw_all.dense_columns(m0, m1)
-            elif ww_all is not None:
-                ww = ww_all[:, m0:m1]
-            else:
-                ww = _pack_w_rows(w_rows_live[m0:m1], w_qp.bits)
-                if compressed_exec:
-                    # §IV-E overlap defers packing per tile: the tile's
-                    # columns still live CSR-compressed and reconstruct
-                    # byte-identically before the MAC
-                    cp = bs.CompressedPlanes.compress(ww)
-                    csr_bytes[0] += cp.payload_bytes
-                    csr_bytes[1] += cp.index_bytes
-                    ww = cp.dense()
-            if engine == "jit" and m1 - m0 < bf:
-                pad = ((0, 0), (0, bf - (m1 - m0))) + ((0, 0),) * (ww.ndim - 2)
-                ww = np.pad(ww, pad)
-            w_cache[mi] = ww
+            with TraceAnnotation("nc.conv.pack"):  # misses only
+                m0, m1 = m_tiles[mi]
+                if cw_all is not None:
+                    ww = cw_all.dense_columns(m0, m1)
+                elif ww_all is not None:
+                    ww = ww_all[:, m0:m1]
+                else:
+                    ww = _pack_w_rows(w_rows_live[m0:m1], w_qp.bits)
+                    if compressed_exec:
+                        # §IV-E overlap defers packing per tile: the
+                        # tile's columns still live CSR-compressed and
+                        # reconstruct byte-identically before the MAC
+                        cp = bs.CompressedPlanes.compress(ww)
+                        csr_bytes[0] += cp.payload_bytes
+                        csr_bytes[1] += cp.index_bytes
+                        ww = cp.dense()
+                if engine == "jit" and m1 - m0 < bf:
+                    pad = (((0, 0), (0, bf - (m1 - m0)))
+                           + ((0, 0),) * (ww.ndim - 2))
+                    ww = np.pad(ww, pad)
+                w_cache[mi] = ww
         return ww
 
     def _x_tile(pi: int) -> np.ndarray:
         xw = x_cache.get(pi)
         if xw is None:
-            p0, p1 = p_tiles[pi]
-            rows = win_flat[p0:p1]
-            if engine == "jit" and rows.shape[0] < bt:
-                rows = np.pad(rows, ((0, bt - rows.shape[0]), (0, 0)))
-            xw = _pack_x_rows(rows, x_qps[0].bits)
-            x_cache[pi] = xw
+            with TraceAnnotation("nc.conv.pack"):  # misses only
+                p0, p1 = p_tiles[pi]
+                rows = win_flat[p0:p1]
+                if engine == "jit" and rows.shape[0] < bt:
+                    rows = np.pad(rows, ((0, bt - rows.shape[0]), (0, 0)))
+                xw = _pack_x_rows(rows, x_qps[0].bits)
+                x_cache[pi] = xw
         return xw
 
     def _store(vals, pi: int, mi: int) -> None:
-        p0, p1 = p_tiles[pi]
-        m0, m1 = m_tiles[mi]
-        v = np.asarray(vals)  # (Mt, T[, expanded rows]); blocks on jit
-        sel = slice(m0, m1) if live_idx is None else live_idx[m0:m1]
-        out[p0:p1, sel] = v[: m1 - m0, : p1 - p0].T
+        with TraceAnnotation("nc.conv.store"):
+            p0, p1 = p_tiles[pi]
+            m0, m1 = m_tiles[mi]
+            v = np.asarray(vals)  # (Mt, T[, expanded rows]); blocks on jit
+            sel = slice(m0, m1) if live_idx is None else live_idx[m0:m1]
+            out[p0:p1, sel] = v[: m1 - m0, : p1 - p0].T
 
     order = [(pi, mi) for pi in range(len(p_tiles))
              for mi in range(len(m_tiles))]
@@ -694,60 +713,63 @@ def nc_conv2d(
             pending = (vals, pi, mi)
         if pending is not None:
             _store(*pending)
-    if zero_mask is not None:
-        # pruned passes: an all-zero filter's dot is the affine constant
-        # zw * sum_k(x_k) — exact, no engine lanes clocked for it
-        row_sums = win_flat.sum(axis=1, dtype=np.int64)
-        out[:, zero_mask] = zw_int * row_sums[:, None]
     total_cycles = per_dot * rows_total * M_live  # one dot per live (b,e,f,m)
     # PR 7: checksum verifications + re-executed tiles charge the same §III
     # formulas as the real work — an additive term, zero when unchecked
     total_cycles += integrity_cycles + reexec_cycles
 
-    # affine-zero-point correction (done by the accumulating requant step
-    # in-cache; exact integer identity — zero points are per image)
-    sx = win.sum(axis=-1)  # (B, E, F)
-    sw = wq.sum(axis=(0, 1, 2))  # (M,)
-    zx = zxs[:, None, None, None]
-    acc = (
-        out.reshape(B, E, F, M)
-        - int(w_qp.zero_point) * sx[..., None]
-        - zx * sw[None, None, None, :]
-        + K * zx * int(w_qp.zero_point)
-    )
-    result = jnp.asarray(acc if batched else acc[0], jnp.int32)
+    with TraceAnnotation("nc.conv.epilogue"):
+        if zero_mask is not None:
+            # pruned passes: an all-zero filter's dot is the affine
+            # constant zw * sum_k(x_k) — exact, no engine lanes clocked
+            row_sums = win_flat.sum(axis=1, dtype=np.int64)
+            out[:, zero_mask] = zw_int * row_sums[:, None]
+        # affine-zero-point correction (done by the accumulating requant
+        # step in-cache; exact integer identity — zero points are per image)
+        sx = win.sum(axis=-1)  # (B, E, F)
+        sw = wq.sum(axis=(0, 1, 2))  # (M,)
+        zx = zxs[:, None, None, None]
+        acc = (
+            out.reshape(B, E, F, M)
+            - int(w_qp.zero_point) * sx[..., None]
+            - zx * sw[None, None, None, :]
+            + K * zx * int(w_qp.zero_point)
+        )
+        result = jnp.asarray(acc if batched else acc[0], jnp.int32)
     if not return_stats:
         return result, total_cycles
-    # separable zero-operand count: sum_k (#zero-free windows_k)*(#zero-free w_k)
-    cx = (win_flat != 0).sum(axis=0).astype(np.int64)  # (K,)
-    cw = (w_rows != 0).sum(axis=0).astype(np.int64)  # (K,)
-    live = int((cx * cw).sum())
-    stats = ConvStats(
-        lanes=rows_total * M * K,
-        zero_operand_lanes=rows_total * M * K - live,
-        tiles=n_tiles,
-        tile_pixels=tile_rows,
-        tile_filters=tile_filters,
-        serial_passes=eff_plan.serial_passes,
-        engine_words_total=bs.SKIP_STATS.words_total - skip0_words,
-        engine_words_skipped=bs.SKIP_STATS.words_skipped - skip0_skipped,
-        batch=B,
-        filter_loads=1,
-        zero_filters=M - M_live,
-        skipped_passes=eff_plan.skipped_passes,
-        overlap=overlap_exec and not checked,  # checked path runs serially
-        integrity=integrity_on,
-        verify_passes=verify_passes,
-        reexec_passes=reexec_passes,
-        faults_detected=faults_detected,
-        integrity_cycles=integrity_cycles,
-        reexec_cycles=reexec_cycles,
-        quarantined_slices=eff_plan.quarantined_slices,
-        compressed=compressed_exec,
-        csr_payload_bytes=csr_bytes[0],
-        csr_index_bytes=csr_bytes[1],
-        plan=eff_plan,
-    )
+    with TraceAnnotation("nc.accounting"):
+        # separable zero-operand count:
+        # sum_k (#zero-free windows_k) * (#zero-free w_k)
+        cx = (win_flat != 0).sum(axis=0).astype(np.int64)  # (K,)
+        cw = (w_rows != 0).sum(axis=0).astype(np.int64)  # (K,)
+        live = int((cx * cw).sum())
+        stats = ConvStats(
+            lanes=rows_total * M * K,
+            zero_operand_lanes=rows_total * M * K - live,
+            tiles=n_tiles,
+            tile_pixels=tile_rows,
+            tile_filters=tile_filters,
+            serial_passes=eff_plan.serial_passes,
+            engine_words_total=bs.SKIP_STATS.words_total - skip0_words,
+            engine_words_skipped=bs.SKIP_STATS.words_skipped - skip0_skipped,
+            batch=B,
+            filter_loads=1,
+            zero_filters=M - M_live,
+            skipped_passes=eff_plan.skipped_passes,
+            overlap=overlap_exec and not checked,  # checked path runs serially
+            integrity=integrity_on,
+            verify_passes=verify_passes,
+            reexec_passes=reexec_passes,
+            faults_detected=faults_detected,
+            integrity_cycles=integrity_cycles,
+            reexec_cycles=reexec_cycles,
+            quarantined_slices=eff_plan.quarantined_slices,
+            compressed=compressed_exec,
+            csr_payload_bytes=csr_bytes[0],
+            csr_index_bytes=csr_bytes[1],
+            plan=eff_plan,
+        )
     return result, total_cycles, stats
 
 

@@ -50,6 +50,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import REGISTRY, get_config, reduced_config
 from repro.kernels import ops
@@ -457,6 +458,18 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
                 return False
             n = decision.admit
         batch = [self.queue.pop(0) for _ in range(n)]
+        # one profiler span per step, shared by every request it serves;
+        # the ids are space-separated (the trace's stat encoding splits
+        # values at commas)
+        with TraceAnnotation("nc.serve.step", batch=n,
+                             request_ids=" ".join(str(r.rid) for r in batch)):
+            self._serve(batch, now)
+        return True
+
+    def _serve(self, batch, now: float) -> None:
+        """Execute one admitted batch through the primary forward (the
+        recovery ladder on failure) and stamp its requests."""
+        n = len(batch)
         x = np.stack([np.asarray(r.image, np.float32) for r in batch])
         t0 = time.perf_counter()
         try:
@@ -483,7 +496,7 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
                         r.slo_ok = False
                         self.slo_misses += 1
                 self.steps += 1
-                return True
+                return
         wall = time.perf_counter() - t0
         if degraded is None:
             if self._warmup_pending and report is not None:
@@ -522,7 +535,6 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
         if report is not None:
             self.reports.append(report)
         self.steps += 1
-        return True
 
     def _recover(self, batch, x, now: float, err: BaseException):
         """Degradation ladder for a failed batch (PR 7).

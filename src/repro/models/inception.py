@@ -23,12 +23,14 @@ BN is inference-folded into a per-channel scale/bias on every conv.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.cache_geometry import CacheGeometry, XEON_E5_35MB
 from repro.core.mapper import LayerSpec
@@ -621,71 +623,80 @@ def _nc_run_conv(name, actq, act_qps, op, wpack, spec, plan, geom, const,
     acc, cycles, stats = nc.nc_conv2d(
         actq, wq, act_qps, w_qp, stride, padding=pad, geom=geom,
         layer_spec=spec, plan=plan, engine=engine, return_stats=True)
-    acc = np.asarray(acc, np.int64)  # [B, E, F, M] int32 staging
-    B = acc.shape[0]
-    # §IV-D epilogue, all in-cache: integer bias add (BN-folded), MSB-masked
-    # ReLU, the min/max log tree, then fixed-point requant.  Only the two
-    # integer scalars per image leave the array.
-    sxw = np.array([np.float32(qp.scale) * np.float32(w_qp.scale)
-                    for qp in act_qps], np.float64)
-    bias_q = np.round(bias[None, :] / sxw[:, None]).astype(np.int64)  # (B, M)
-    acc = np.maximum(acc + bias_q[:, None, None, :], 0)
-    mn, mx, c_mm = nc.nc_minmax(acc.reshape(B, -1), bits=32, signed=True)
-    cycles += int(c_mm)
-    yq = np.empty(acc.shape, np.uint8)
-    out_qps = []
-    for b in range(B):
-        # the CPU-side scalar step: two integers in, multiplier + zp out
-        qp = q.choose_qparams(jnp.float32(mn[b] * sxw[b]),
-                              jnp.float32(mx[b] * sxw[b]))
-        yq[b] = _requant_image(acc[b], sxw[b] / float(qp.scale),
-                               int(qp.zero_point))
-        out_qps.append(qp)
+    with TraceAnnotation("nc.conv.epilogue"):
+        acc = np.asarray(acc, np.int64)  # [B, E, F, M] int32 staging
+        B = acc.shape[0]
+        # §IV-D epilogue, all in-cache: integer bias add (BN-folded),
+        # MSB-masked ReLU, the min/max log tree, then fixed-point requant.
+        # Only the two integer scalars per image leave the array.
+        sxw = np.array([np.float32(qp.scale) * np.float32(w_qp.scale)
+                        for qp in act_qps], np.float64)
+        bias_q = np.round(bias[None, :] / sxw[:, None]).astype(np.int64)
+        acc = np.maximum(acc + bias_q[:, None, None, :], 0)
+        mn, mx, c_mm = nc.nc_minmax(acc.reshape(B, -1), bits=32, signed=True)
+        cycles += int(c_mm)
+        yq = np.empty(acc.shape, np.uint8)
+        out_qps = []
+        for b in range(B):
+            # the CPU-side scalar step: two integers in, multiplier + zp out
+            qp = q.choose_qparams(jnp.float32(mn[b] * sxw[b]),
+                                  jnp.float32(mx[b] * sxw[b]))
+            yq[b] = _requant_image(acc[b], sxw[b] / float(qp.scale),
+                                   int(qp.zero_point))
+            out_qps.append(qp)
     cycles += B * plan.quant_passes * _REQUANT_PASS_CYCLES
-    # measured output occupancy for warmup re-planning: a lane holding the
-    # image's zero point is an exact zero activation, so the max over the
-    # batch of live (non-zero-point) output bytes is what the §IV-D
-    # requant passes must actually cover
-    live_out = max(int((yq[b] != int(out_qps[b].zero_point)).sum())
-                   for b in range(B))
-    # quarantine re-plans mid-layer: price the plan the engine actually
-    # executed, plus the exact per-pass price of each fault re-execution
-    eff_plan = stats.plan if stats.plan is not None else plan
-    modeled = sim.modeled_layer_cycles(eff_plan, geom, const)
-    records.append(NCLayerReport(
-        name=name, kind="conv", out_shape=tuple(yq.shape),
-        emulated_cycles=int(cycles),
-        modeled_cycles=(modeled["total_cycles"]
-                        + stats.reexec_passes * modeled["reexec_pass_cycles"]),
-        serial_passes=modeled["serial_passes"], modeled_s=modeled["total_s"],
-        lanes=stats.lanes, zero_operand_lanes=stats.zero_operand_lanes,
-        batch=B, minmax_cycles=int(c_mm), filter_loads=stats.filter_loads,
-        skipped_passes=modeled["skipped_passes"],
-        zero_filters=stats.zero_filters, overlap=stats.overlap,
-        integrity=stats.integrity, reexec_passes=stats.reexec_passes,
-        faults_detected=stats.faults_detected,
-        quarantined_slices=stats.quarantined_slices,
-        live_output_bytes=live_out))
+    with TraceAnnotation("nc.accounting"):
+        # measured output occupancy for warmup re-planning: a lane holding
+        # the image's zero point is an exact zero activation, so the max
+        # over the batch of live (non-zero-point) output bytes is what the
+        # §IV-D requant passes must actually cover
+        live_out = max(int((yq[b] != int(out_qps[b].zero_point)).sum())
+                       for b in range(B))
+        # quarantine re-plans mid-layer: price the plan the engine actually
+        # executed, plus the exact per-pass price of each fault re-execution
+        eff_plan = stats.plan if stats.plan is not None else plan
+        modeled = sim.modeled_layer_cycles(eff_plan, geom, const)
+        records.append(NCLayerReport(
+            name=name, kind="conv", out_shape=tuple(yq.shape),
+            emulated_cycles=int(cycles),
+            modeled_cycles=(modeled["total_cycles"]
+                            + stats.reexec_passes
+                            * modeled["reexec_pass_cycles"]),
+            serial_passes=modeled["serial_passes"],
+            modeled_s=modeled["total_s"],
+            lanes=stats.lanes, zero_operand_lanes=stats.zero_operand_lanes,
+            batch=B, minmax_cycles=int(c_mm),
+            filter_loads=stats.filter_loads,
+            skipped_passes=modeled["skipped_passes"],
+            zero_filters=stats.zero_filters, overlap=stats.overlap,
+            integrity=stats.integrity, reexec_passes=stats.reexec_passes,
+            faults_detected=stats.faults_detected,
+            quarantined_slices=stats.quarantined_slices,
+            live_output_bytes=live_out))
     return yq, out_qps
 
 
 def _nc_run_pool(name, actq, act_qps, op, spec, geom, const, records):
     kind, r, stride, pad = op
-    if kind == "maxpool":
-        out_q, cycles = nc.nc_maxpool2d(actq, r, stride, padding=pad)
-    else:
-        out_q, cycles = nc.nc_avgpool2d(actq, r, stride, padding=pad)
-    out_q = np.asarray(out_q, np.uint8)
-    modeled = sim.modeled_layer_cycles(spec, geom, const)  # pools never skip
-    records.append(NCLayerReport(
-        name=name, kind=kind, out_shape=tuple(out_q.shape),
-        emulated_cycles=int(cycles), modeled_cycles=modeled["total_cycles"],
-        serial_passes=modeled["serial_passes"], modeled_s=modeled["total_s"],
-        batch=out_q.shape[0]))
+    with TraceAnnotation("nc.pool"):
+        if kind == "maxpool":
+            out_q, cycles = nc.nc_maxpool2d(actq, r, stride, padding=pad)
+        else:
+            out_q, cycles = nc.nc_avgpool2d(actq, r, stride, padding=pad)
+        out_q = np.asarray(out_q, np.uint8)
+    with TraceAnnotation("nc.accounting"):
+        modeled = sim.modeled_layer_cycles(spec, geom, const)  # never skip
+        records.append(NCLayerReport(
+            name=name, kind=kind, out_shape=tuple(out_q.shape),
+            emulated_cycles=int(cycles),
+            modeled_cycles=modeled["total_cycles"],
+            serial_passes=modeled["serial_passes"],
+            modeled_s=modeled["total_s"], batch=out_q.shape[0]))
     # pooling is order/affine-transparent: quantization passes through
     return out_q, act_qps
 
 
+@functools.partial(jax.profiler.annotate_function, name="nc.concat")
 def _nc_concat(outs, state):
     """Concatenate branch outputs along channels, requantizing every branch
     to a per-image common scale in-cache (branches carry their own dynamic
@@ -713,11 +724,13 @@ def _nc_concat(outs, state):
 def _nc_apply_op(actq, act_qps, name, op, wpack, specs, plans, geom, const,
                  engine, records, state):
     if op[0] == "conv":
-        return _nc_run_conv(name, actq, act_qps, op, wpack, specs[name],
-                            plans[name], geom, const, engine, records)
+        with TraceAnnotation("nc.layer", layer=name):
+            return _nc_run_conv(name, actq, act_qps, op, wpack, specs[name],
+                                plans[name], geom, const, engine, records)
     if op[0] in ("maxpool", "avgpool"):
-        return _nc_run_pool(name, actq, act_qps, op, specs[name], geom,
-                            const, records)
+        with TraceAnnotation("nc.layer", layer=name):
+            return _nc_run_pool(name, actq, act_qps, op, specs[name], geom,
+                                const, records)
     if op[0] == "split":
         outs = []
         for i, sub in enumerate(op[1:]):
@@ -765,36 +778,45 @@ def _nc_stage_gen(x4, config, wpack, specs, plans, geom, const, engine,
         yield bname
     # global average pool through the array, then FC as a 1x1 conv
     h = actq.shape[1]
-    actq, act_qps = _nc_run_pool("AvgPool", actq, act_qps,
-                                 ("avgpool", h, 1, "VALID"),
-                                 specs["AvgPool"], geom, const, records)
+    with TraceAnnotation("nc.layer", layer="AvgPool"):
+        actq, act_qps = _nc_run_pool("AvgPool", actq, act_qps,
+                                     ("avgpool", h, 1, "VALID"),
+                                     specs["AvgPool"], geom, const, records)
     actq = actq.reshape(B, -1)
     wq, w_qp, fc_bias = wpack["FullyConnected"]
     spec = specs["FullyConnected"]
-    acc, cycles, stats = nc.nc_fc(actq, wq[0, 0], act_qps, w_qp, geom=geom,
-                                  layer_spec=spec,
-                                  plan=plans["FullyConnected"],
-                                  engine=engine, return_stats=True)
-    sxw = np.array([np.float32(qp.scale) * np.float32(w_qp.scale)
-                    for qp in act_qps], np.float32)
-    logits = (np.asarray(acc, np.float32) * sxw[:, None]
-              + fc_bias[None, :].astype(np.float32))
-    eff_plan = (stats.plan if stats.plan is not None
-                else plans["FullyConnected"])
-    modeled = sim.modeled_layer_cycles(eff_plan, geom, const)
-    records.append(NCLayerReport(
-        name="FullyConnected", kind="fc", out_shape=tuple(logits.shape),
-        emulated_cycles=int(cycles),
-        modeled_cycles=(modeled["total_cycles"]
-                        + stats.reexec_passes * modeled["reexec_pass_cycles"]),
-        serial_passes=modeled["serial_passes"], modeled_s=modeled["total_s"],
-        lanes=stats.lanes, zero_operand_lanes=stats.zero_operand_lanes,
-        batch=x4.shape[0], filter_loads=stats.filter_loads,
-        skipped_passes=modeled["skipped_passes"],
-        zero_filters=stats.zero_filters, overlap=stats.overlap,
-        integrity=stats.integrity, reexec_passes=stats.reexec_passes,
-        faults_detected=stats.faults_detected,
-        quarantined_slices=stats.quarantined_slices))
+    with TraceAnnotation("nc.layer", layer="FullyConnected"):
+        acc, cycles, stats = nc.nc_fc(actq, wq[0, 0], act_qps, w_qp,
+                                      geom=geom, layer_spec=spec,
+                                      plan=plans["FullyConnected"],
+                                      engine=engine, return_stats=True)
+        with TraceAnnotation("nc.conv.epilogue"):
+            sxw = np.array([np.float32(qp.scale) * np.float32(w_qp.scale)
+                            for qp in act_qps], np.float32)
+            logits = (np.asarray(acc, np.float32) * sxw[:, None]
+                      + fc_bias[None, :].astype(np.float32))
+        with TraceAnnotation("nc.accounting"):
+            eff_plan = (stats.plan if stats.plan is not None
+                        else plans["FullyConnected"])
+            modeled = sim.modeled_layer_cycles(eff_plan, geom, const)
+            records.append(NCLayerReport(
+                name="FullyConnected", kind="fc",
+                out_shape=tuple(logits.shape),
+                emulated_cycles=int(cycles),
+                modeled_cycles=(modeled["total_cycles"]
+                                + stats.reexec_passes
+                                * modeled["reexec_pass_cycles"]),
+                serial_passes=modeled["serial_passes"],
+                modeled_s=modeled["total_s"],
+                lanes=stats.lanes,
+                zero_operand_lanes=stats.zero_operand_lanes,
+                batch=x4.shape[0], filter_loads=stats.filter_loads,
+                skipped_passes=modeled["skipped_passes"],
+                zero_filters=stats.zero_filters, overlap=stats.overlap,
+                integrity=stats.integrity,
+                reexec_passes=stats.reexec_passes,
+                faults_detected=stats.faults_detected,
+                quarantined_slices=stats.quarantined_slices))
     state["logits"] = logits
     yield "FullyConnected"
 
@@ -828,6 +850,7 @@ def _merge_chunk_records(per_chunk: list[list[NCLayerReport]],
     return merged
 
 
+@functools.partial(jax.profiler.annotate_function, name="nc.forward")
 def nc_forward(params: dict, x: jax.Array,
                config: InceptionConfig = REDUCED,
                geom: CacheGeometry = XEON_E5_35MB,
@@ -917,6 +940,14 @@ def nc_forward(params: dict, x: jax.Array,
     Returns ``(logits [B?, classes], NCForwardReport)`` — the report pairs
     each layer's emulated arithmetic cycles (min/max tree included) with
     the analytic model's serialized-pass cycles and modeled wall time.
+
+    Profiler spans (recorded only while a profiler session runs): the
+    call is one ``nc.forward``; each conv, pool and the FC one
+    ``nc.layer`` with a ``layer`` stat naming it; inside them the host
+    stages ``nc.conv.epilogue`` (bias, ReLU, min/max tree, requant),
+    ``nc.pool`` and ``nc.accounting`` (modeled cycles and the report),
+    and ``nc.concat`` at each branch concatenation.  docs/SERVING.md
+    lists every span.
     """
     xin = np.asarray(x, np.float32)
     batched = xin.ndim == 4

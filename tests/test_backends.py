@@ -181,6 +181,26 @@ def test_pallas_interpret_dot_smoke():
     assert backends.dispatch_stats()["pallas"]["native"] == 1
 
 
+def test_dispatch_stats_count_the_bytes_of_a_pallas_call():
+    """``bytes_to_device`` is the two word grids handed to the device,
+    rows padded to their buckets; ``bytes_from_device`` the padded int32
+    result copied back.  A clear zeroes both with the call counts."""
+    rng = np.random.default_rng(5)
+    K = 64
+    xw = nc._pack_x_rows(rng.integers(0, 256, size=(5, K)), 8)
+    ww = nc._pack_w_rows(rng.integers(0, 256, size=(3, K)), 8)
+    backends.dispatch_stats_clear()
+    bs.packed_dot_words(xw, ww, K=K, acc_bits=32, engine="pallas")
+    rx, rw = bs.bucket_words(5), bs.bucket_words(3)
+    words = xw.shape[-1]  # per row: K lanes of one plane in uint32 words
+    assert backends.dispatch_stats()["pallas"] == {
+        "native": 1, "fallback": 0,
+        "bytes_to_device": 4 * 8 * (rx + rw) * words,
+        "bytes_from_device": 4 * rx * rw}
+    backends.dispatch_stats_clear()
+    assert set(backends.dispatch_stats()["pallas"].values()) == {0}
+
+
 # ---------------------------------------------------------------------------
 # Satellite: unknown backend names raise, naming the registry
 # ---------------------------------------------------------------------------
@@ -374,7 +394,8 @@ def test_dispatch_stats_count_fallbacks():
                                   engine="pallas")
     np.testing.assert_array_equal(np.asarray(vals), np.asarray(ref))
     st = backends.dispatch_stats()["pallas"]
-    assert st == {"native": 0, "fallback": 1}
+    assert st == {"native": 0, "fallback": 1, "bytes_to_device": 0,
+                  "bytes_from_device": 0}
 
 
 def test_pallas_on_tpu_raises_instead_of_falling_back(monkeypatch):
@@ -389,8 +410,9 @@ def test_pallas_on_tpu_raises_instead_of_falling_back(monkeypatch):
     backends.dispatch_stats_clear()
     with pytest.raises(ValueError, match="rows share words"):
         bs.packed_dot_words(xw, ww, K=9, acc_bits=32, engine="pallas")
-    assert backends.dispatch_stats()["pallas"] == {"native": 0,
-                                                   "fallback": 0}
+    assert backends.dispatch_stats()["pallas"] == {
+        "native": 0, "fallback": 0, "bytes_to_device": 0,
+        "bytes_from_device": 0}
 
 
 def test_tpu_platform_defaults_to_pallas(monkeypatch):
