@@ -152,7 +152,7 @@ def main() -> int:
     disp = backends.dispatch_stats()
     _log(f"batch walls (s, host clock, first includes compiles): {walls}")
     _log(f"compiles {compiles['n']} in {compiles['s']:.3f} s, of which "
-         f"{backends._pallas_exact._cache_size()} pallas tile programs; "
+         f"{backends._pallas_exact._cache_size()} pallas adapter programs; "
          f"dispatch {disp}")
     assert len(done) == N_IMAGES, (len(done), st["errors"])
     assert st["failed"] == 0 and st["degraded_batches"] == 0, st

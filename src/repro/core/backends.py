@@ -51,9 +51,11 @@ Registered backends
     The byte-packed Pallas bit-serial GEMM (in-kernel shift+mask plane
     unpack, zero-plane-block skip; the W4A4 nibble kernel when both
     operands fit 4 planes).  The word grids decode to integer rows on
-    the device inside one jitted program with the kernel; ragged tiles
-    pad to :func:`~repro.core.bitserial.bucket_words` rows so repeated
-    tile shapes share one executable.  Compiled on a TPU, run through
+    the device inside one jitted program with the kernel; rows pad to
+    :func:`~repro.core.bitserial.bucket_words` so repeated shapes share
+    one executable.  It declares ``layer_calls``: ``nc_conv2d`` hands it
+    a layer's whole pass list, so a conv layer's packed operands go to
+    the device once, not once per plan tile.  Compiled on a TPU, run through
     the Pallas interpreter on CPU (``kernels/ops.py`` decides).  On CPU,
     inputs outside its native envelope (traced operands, rows sharing
     words — ``K <= 16`` —, > 8 planes, int32-overflow risk,
@@ -100,10 +102,17 @@ class Backend:
     The capability flags describe the *native* envelope; inputs outside
     it are delegated to the host body on CPU (still byte-exact — see the
     module contract) and raise on a TPU.
-    ``dot_words(xw, ww, *, K, acc_bits, materialize)`` returns the
-    integer row values only; cycles are charged by the
+    ``dot_words(xw, ww, *, K, acc_bits, materialize, passes)`` returns
+    the integer row values only; cycles are charged by the
     caller (``bitserial.packed_dot_words``) so backends cannot perturb
-    the cycle model."""
+    the cycle model.  ``passes`` is the number of plan passes the call
+    serves, for the dispatch counters.
+
+    ``layer_calls`` declares that one call may carry a whole layer's
+    pass list: ``nc_conv2d``'s unchecked loop then gathers every row
+    tile's packed windows and every filter tile's packed columns and
+    dispatches once per layer (more calls only where an operand grid
+    would pass ``max_lane_words``), instead of once per plan tile."""
 
     name: str
     # accumulator widths executed natively (None = any)
@@ -113,6 +122,7 @@ class Backend:
     integrity: bool  # safe under the ABFT checked/fault-injected path
     # cap on one operand's word-grid size (None = unbounded)
     max_lane_words: int | None
+    layer_calls: bool  # takes a layer's whole pass list in one call
     dot_words: Callable[..., np.ndarray]
 
     def supports_acc(self, acc_bits: int) -> bool:
@@ -122,7 +132,7 @@ class Backend:
 _REGISTRY: dict[str, Backend] = {}
 # per-backend dispatch counters, in the order of _DISPATCH_KEYS
 _DISPATCH_KEYS = ("native", "fallback", "bytes_to_device",
-                  "bytes_from_device")
+                  "bytes_from_device", "passes")
 _DISPATCH: dict[str, list[int]] = {}
 
 
@@ -183,11 +193,14 @@ def resolve_backend(explicit: str | None = None,
 def dispatch_stats() -> dict[str, dict[str, int]]:
     """Per-backend dispatch counters since the last clear:
     ``{name: {"native": n, "fallback": m, "bytes_to_device": a,
-    "bytes_from_device": b}}`` — ``fallback`` counts calls delegated to
-    the host body (inputs outside the native envelope); the byte counts
-    are the operands handed to the device and the results copied back,
-    padding included (``pallas`` only: the other bodies run on the
-    host or keep their transfers inside XLA)."""
+    "bytes_from_device": b, "passes": p}}`` — ``fallback`` counts calls
+    delegated to the host body (inputs outside the native envelope); the
+    byte counts are the operands handed to the device and the results
+    copied back, padding included (``pallas`` only: the other bodies run
+    on the host or keep their transfers inside XLA); ``passes`` counts
+    the plan passes that native calls served, so ``passes / native`` is
+    passes per call (1 per tile; a layer's tiles on a ``layer_calls``
+    backend)."""
     return {name: dict(zip(_DISPATCH_KEYS, c))
             for name, c in _DISPATCH.items()}
 
@@ -198,19 +211,21 @@ def dispatch_stats_clear() -> None:
 
 
 def _note(name: str, native: bool, to_device: int = 0,
-          from_device: int = 0) -> None:
+          from_device: int = 0, passes: int = 1) -> None:
     c = _DISPATCH[name]
     c[0 if native else 1] += 1
     c[2] += to_device
     c[3] += from_device
+    if native:
+        c[4] += passes
 
 
 # ---------------------------------------------------------------------------
 # host — the exact reference body
 # ---------------------------------------------------------------------------
 def _host_dot_words(xw, ww, *, K: int, acc_bits: int,
-                    materialize: bool = True):
-    _note("host", native=True)
+                    materialize: bool = True, passes: int = 1):
+    _note("host", native=True, passes=passes)
     return bs._dot_words_impl(xw, ww, K=K, acc_bits=acc_bits)
 
 
@@ -218,7 +233,8 @@ def _host_dot_words(xw, ww, *, K: int, acc_bits: int,
 # jit — bucketed compiled decoded-lane kernel (cache lives in bitserial so
 # engine_cache_info/engine_cache_clear keep reporting it)
 # ---------------------------------------------------------------------------
-def _jit_dot_words(xw, ww, *, K: int, acc_bits: int, materialize: bool = True):
+def _jit_dot_words(xw, ww, *, K: int, acc_bits: int, materialize: bool = True,
+                   passes: int = 1):
     if bs._is_traced(xw, ww):
         _note("jit", native=False)
         return bs._dot_words_impl(xw, ww, K=K, acc_bits=acc_bits)
@@ -233,7 +249,7 @@ def _jit_dot_words(xw, ww, *, K: int, acc_bits: int, materialize: bool = True):
         fn = jax.jit(functools.partial(bs._dot_words_decoded, K=K,
                                        acc_bits=acc_bits))
         bs._ENGINE_CACHE[key] = fn
-    _note("jit", native=True)
+    _note("jit", native=True, passes=passes)
     out = fn(jnp.asarray(xw), jnp.asarray(ww))
     return np.asarray(out) if materialize else out
 
@@ -301,7 +317,7 @@ def _pad_rows(words: np.ndarray, rows: int) -> np.ndarray:
 
 
 def _pallas_dot_words(xw, ww, *, K: int, acc_bits: int,
-                      materialize: bool = True):
+                      materialize: bool = True, passes: int = 1):
     """Adapter: flatten the two row-aligned word grids, pad their rows to
     the bucketed-jit engine's buckets, decode and run the byte-packed
     Pallas kernel in one device program (the W4A4 nibble kernel when
@@ -334,7 +350,7 @@ def _pallas_dot_words(xw, ww, *, K: int, acc_bits: int,
         _note("pallas", native=False)
         return bs._dot_words_impl(xw, ww, K=K, acc_bits=acc_bits)
     _note("pallas", native=True, to_device=xf.nbytes + wf.nbytes,
-          from_device=out.nbytes)
+          from_device=out.nbytes, passes=passes)
     with TraceAnnotation("nc.pallas.wait"):
         O = np.asarray(out)  # exact int32 accumulator, padded
 
@@ -351,11 +367,13 @@ def _pallas_dot_words(xw, ww, *, K: int, acc_bits: int,
 
 register_backend(Backend(
     name="host", acc_bits=None, w4a4=True, compressed_planes=True,
-    integrity=True, max_lane_words=None, dot_words=_host_dot_words))
+    integrity=True, max_lane_words=None, layer_calls=False,
+    dot_words=_host_dot_words))
 register_backend(Backend(
     name="jit", acc_bits=None, w4a4=True, compressed_planes=True,
-    integrity=True, max_lane_words=None, dot_words=_jit_dot_words))
+    integrity=True, max_lane_words=None, layer_calls=False,
+    dot_words=_jit_dot_words))
 register_backend(Backend(
     name="pallas", acc_bits=(24, 32), w4a4=True,
     compressed_planes=True, integrity=True, max_lane_words=1 << 22,
-    dot_words=_pallas_dot_words))
+    layer_calls=True, dot_words=_pallas_dot_words))

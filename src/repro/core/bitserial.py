@@ -1293,7 +1293,7 @@ def _dot_words_decoded(xw, ww, *, K: int, acc_bits: int):
 
 def packed_dot_words(xw, ww, *, K: int, acc_bits: int,
                      engine: str | None = "host",
-                     materialize: bool = True):
+                     materialize: bool = True, passes: int = 1):
     """Fused row-aligned dot: ``sum_k x[row, k] * w[row, k]`` per row.
 
     ``xw``/``ww`` are word arrays of shape ``(n_planes, *grid, row_words)``
@@ -1327,6 +1327,10 @@ def packed_dot_words(xw, ww, *, K: int, acc_bits: int,
     in core/nc_layers.py defers ``np.asarray`` by one tile.  Values are
     identical either way; synchronous backends only change WHEN the copy
     happens, never what it holds.
+
+    ``passes`` is the number of plan passes the grids carry (a layer's
+    whole pass list on a backend that declares ``layer_calls``); it feeds
+    the backend's dispatch counters and nothing else.
     """
     from repro.core import backends as _backends
 
@@ -1336,7 +1340,7 @@ def packed_dot_words(xw, ww, *, K: int, acc_bits: int,
     n_bits = max(xw.shape[0], ww.shape[0])
     cycles = dot_cycles(K, n_bits, acc_bits)
     vals = backend.dot_words(xw, ww, K=K, acc_bits=acc_bits,
-                             materialize=materialize)
+                             materialize=materialize, passes=passes)
     return vals, cycles
 
 

@@ -238,6 +238,21 @@ def _pack_w_rows(rows: np.ndarray, n_bits: int) -> np.ndarray:
     return out.astype(np.uint32)[:, :, None]
 
 
+def _call_groups(sizes: list[int], cap: int | None) -> list[list[int]]:
+    """Consecutive tile indices grouped so each group's summed word-grid
+    size stays within ``cap`` (one group when unbounded; a tile larger
+    than ``cap`` alone is a group of its own)."""
+    groups: list[list[int]] = []
+    total = 0
+    for i, size in enumerate(sizes):
+        if not groups or (cap is not None and total + size > cap):
+            groups.append([])
+            total = 0
+        groups[-1].append(i)
+        total += size
+    return groups
+
+
 def nc_conv2d(
     x: jax.Array,
     w: jax.Array,
@@ -347,12 +362,24 @@ def nc_conv2d(
     overlap and integrity, compression is a plan decision:
     ``compressed=True`` alongside an explicit plan raises.
 
+    Layer-granular dispatch: on the unchecked path, a backend that
+    declares ``layer_calls`` (``pallas``) runs one device program for
+    the layer's whole pass list.  The load stage is unchanged (each row
+    tile and filter tile packs once, as above); the packed rows are
+    gathered along the row axis and the packed filter columns along the
+    filter axis, and whole tiles split into further calls only where an
+    operand grid would pass the backend's ``max_lane_words``.  The int32
+    sums do not depend on how passes are grouped, so values, cycles and
+    :class:`ConvStats` (``tiles`` still counts the plan's passes) are
+    those of the per-tile loop, which ``host``, ``jit`` and the checked
+    path keep.
+
     Profiler spans (``jax.profiler.TraceAnnotation``, recorded only while
     a profiler session runs; docs/SERVING.md lists them all):
     ``nc.conv.im2col`` (quantize, pad, window extraction, lane casts,
     occupancy validation), ``nc.conv.pack`` (the per-layer filter pack
     and each miss of the per-tile window and filter caches),
-    ``nc.conv.store`` (each tile's values into the output),
+    ``nc.conv.store`` (each device call's values into the output),
     ``nc.conv.epilogue`` (pruned-filter fill, zero-point correction, the
     result array) and ``nc.accounting`` (the :class:`ConvStats` counts).
     """
@@ -552,10 +579,11 @@ def nc_conv2d(
                 x_cache[pi] = xw
         return xw
 
-    def _store(vals, pi: int, mi: int) -> None:
+    def _store(vals, rows: tuple[int, int], cols: tuple[int, int]) -> None:
+        """Write one call's block, live filters ``cols`` x rows ``rows``."""
         with TraceAnnotation("nc.conv.store"):
-            p0, p1 = p_tiles[pi]
-            m0, m1 = m_tiles[mi]
+            p0, p1 = rows
+            m0, m1 = cols
             v = np.asarray(vals)  # (Mt, T[, expanded rows]); blocks on jit
             sel = slice(m0, m1) if live_idx is None else live_idx[m0:m1]
             out[p0:p1, sel] = v[: m1 - m0, : p1 - p0].T
@@ -568,6 +596,9 @@ def nc_conv2d(
     fs = faults.active()
     integrity_on = bool(plan.integrity)
     checked = integrity_on or fs is not None
+    backend = _backends.get_backend(engine)
+    # rows sharing words (K <= 16) cannot be gathered along the row axis
+    layer_calls = backend.layer_calls and bs._row_layout(K)[2] == 1
     eff_plan = plan
     verify_passes = reexec_passes = faults_detected = 0
     integrity_cycles = reexec_cycles = 0
@@ -687,7 +718,28 @@ def nc_conv2d(
                     quarantined_slices=tuple(sorted(fs.quarantined)),
                     compressed=plan.compressed)
                 attempts = 0
-            _store(v2, pi, mi)
+            _store(v2, p_tiles[pi], m_tiles[mi])
+    elif layer_calls:
+        # one device call serves many plan passes: the load stage still
+        # packs each row tile and filter tile once, then the packed
+        # windows are gathered along the row axis and the packed filter
+        # columns along the filter axis; whole tiles split into more
+        # calls only where a grid would pass the backend's cap
+        cap = backend.max_lane_words
+        xs = [_x_tile(pi) for pi in range(len(p_tiles))]
+        ws = [_filter_tile(mi) for mi in range(len(m_tiles))]
+        w_calls = [(np.concatenate([ws[i] for i in mg], axis=1),
+                    (m_tiles[mg[0]][0], m_tiles[mg[-1]][1]), len(mg))
+                   for mg in _call_groups([a.size for a in ws], cap)]
+        for pg in _call_groups([a.size for a in xs], cap):
+            xw = np.concatenate([xs[i] for i in pg], axis=2)
+            rows = (p_tiles[pg[0]][0], p_tiles[pg[-1]][1])
+            for ww, cols, n_m in w_calls:
+                vals, _ = bs.packed_dot_words(
+                    xw, ww, K=K, acc_bits=acc_bits, engine=engine,
+                    passes=len(pg) * n_m)
+                _store(vals, rows, cols)
+        n_tiles = len(p_tiles) * len(m_tiles)
     else:
         pending = None  # §IV-E double buffer: one dispatched tile in flight
         for t, (pi, mi) in enumerate(order):
@@ -698,7 +750,7 @@ def nc_conv2d(
                 engine=engine, materialize=not overlap_exec)
             n_tiles += 1
             if not overlap_exec:
-                _store(vals, pi, mi)
+                _store(vals, p_tiles[pi], m_tiles[mi])
                 continue
             # tile t's MAC+reduce is in flight (asynchronous dispatch): run
             # tile t+1's load stage NOW — pack the next pass's filter columns
@@ -710,7 +762,7 @@ def nc_conv2d(
                 _x_tile(npi)
             if pending is not None:
                 _store(*pending)
-            pending = (vals, pi, mi)
+            pending = (vals, p_tiles[pi], m_tiles[mi])
         if pending is not None:
             _store(*pending)
     total_cycles = per_dot * rows_total * M_live  # one dot per live (b,e,f,m)

@@ -23,6 +23,11 @@ differentially:
   names raise a :class:`ValueError` listing the registered backends, and
   switching needs zero call-site edits (asserted via
   ``backends.dispatch_stats``).
+* **Layer-granular dispatch** — on the unchecked path the ``pallas``
+  backend (``layer_calls``) serves a conv layer's whole pass list in one
+  call, split at whole row tiles only under its ``max_lane_words``, with
+  values, cycles and ``ConvStats`` those of the per-tile runs; ``host``,
+  ``jit`` and the checked path still call once per pass.
 * **Compile-cache reuse** — the bucketed-jit backend compiles exactly
   once per (planes, acc, K) bucket even when the same shapes flow
   through DIFFERENT layers (``engine_cache_info`` reporting matches).
@@ -32,6 +37,8 @@ parametrizations carry the ``backends`` marker (the interpreter is slow)
 and run under benchmarks/run.py's gate or
 ``pytest -m backends -o addopts=``.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -196,9 +203,121 @@ def test_dispatch_stats_count_the_bytes_of_a_pallas_call():
     assert backends.dispatch_stats()["pallas"] == {
         "native": 1, "fallback": 0,
         "bytes_to_device": 4 * 8 * (rx + rw) * words,
-        "bytes_from_device": 4 * rx * rw}
+        "bytes_from_device": 4 * rx * rw, "passes": 1}
     backends.dispatch_stats_clear()
     assert set(backends.dispatch_stats()["pallas"].values()) == {0}
+
+
+# ---------------------------------------------------------------------------
+# Layer-granular dispatch: a ``layer_calls`` backend takes a layer's whole
+# pass list in one call on the unchecked path
+# ---------------------------------------------------------------------------
+# (conv case, nc_conv2d options): tiles are small so every layer has
+# several row tiles and filter tiles
+LAYER_CASES = [
+    pytest.param(dict(C=4), dict(tile_pixels=7, tile_filters=2),
+                 id="dense-K36"),
+    pytest.param(dict(C=4, prune=0.5),
+                 dict(tile_pixels=7, tile_filters=2, occupancy="detect"),
+                 id="occupancy-zero-filters"),
+    pytest.param(dict(C=4, prune=0.5),
+                 dict(tile_pixels=7, tile_filters=2, occupancy="detect",
+                      compressed=True, overlap=True),
+                 id="compressed-overlap"),
+    pytest.param(dict(C=4, batch=2),
+                 dict(tile_pixels=20, tile_filters=4, padding="SAME"),
+                 id="batch2"),
+    pytest.param(dict(C=3, M=8),
+                 dict(tile_pixels=9, tile_filters=3, overlap=True),
+                 id="stem-3x3x3"),
+]
+
+
+def _layer_run(conv, opts, engine):
+    kw = dict(conv)
+    xq, wq, qps, w_qp = _quantized_conv_case(0xBEEF, **kw)
+    backends.dispatch_stats_clear()
+    out, cycles, stats = nc.nc_conv2d(xq, wq, qps, w_qp, geom=GEOM,
+                                      engine=engine, return_stats=True,
+                                      **opts)
+    return np.asarray(out), cycles, stats, backends.dispatch_stats()[engine]
+
+
+def _per_tile_pallas(monkeypatch):
+    pal = backends.get_backend("pallas")
+    monkeypatch.setitem(backends._REGISTRY, "pallas",
+                        dataclasses.replace(pal, layer_calls=False))
+
+
+@pytest.mark.parametrize("conv,opts", LAYER_CASES)
+def test_pallas_layer_call_matches_the_per_tile_runs(conv, opts, monkeypatch):
+    """One native call per layer, serving every plan pass: values and
+    modeled cycles equal the per-tile host run's, every ``ConvStats``
+    field too (the host multiply's own word counters aside: no host
+    multiply runs on ``pallas``), and ``ConvStats`` equals the per-tile
+    ``pallas`` run's field for field."""
+    ref, ref_cycles, ref_stats, _ = _layer_run(conv, opts, "host")
+    out, cycles, stats, st = _layer_run(conv, opts, "pallas")
+    np.testing.assert_array_equal(out, ref)
+    assert cycles == ref_cycles
+    host_only = {"engine_words_total", "engine_words_skipped"}
+    assert ({k: v for k, v in dataclasses.asdict(stats).items()
+             if k not in host_only}
+            == {k: v for k, v in dataclasses.asdict(ref_stats).items()
+                if k not in host_only})
+    assert stats.tiles > 1
+    assert st["native"] == 1 and st["fallback"] == 0
+    assert st["passes"] == stats.tiles
+
+    _per_tile_pallas(monkeypatch)
+    tiled, tiled_cycles, tiled_stats, tiled_st = _layer_run(conv, opts,
+                                                            "pallas")
+    np.testing.assert_array_equal(tiled, ref)
+    assert tiled_cycles == cycles
+    assert tiled_stats == stats
+    assert tiled_st["native"] == tiled_st["passes"] == stats.tiles
+
+
+def test_pallas_layer_call_splits_at_whole_row_tiles(monkeypatch):
+    """A cap below the layer's window grid splits the call at whole plan
+    row tiles, greedily, with the same values: 36 rows in tiles of 7
+    (112 words each at K=36, the last 16) under a 224-word cap go in
+    three calls of 14, 14 and 8 rows."""
+    conv, opts = dict(C=4), dict(tile_pixels=7, tile_filters=6)
+    ref, ref_cycles, ref_stats, _ = _layer_run(conv, opts, "host")
+    pal = backends.get_backend("pallas")
+    monkeypatch.setitem(backends._REGISTRY, "pallas",
+                        dataclasses.replace(pal, max_lane_words=224))
+    rows = []
+    dot = bs.packed_dot_words
+
+    def spy(xw, ww, **kw):
+        rows.append(xw.shape[2])
+        return dot(xw, ww, **kw)
+
+    monkeypatch.setattr(bs, "packed_dot_words", spy)
+    out, cycles, stats, st = _layer_run(conv, opts, "pallas")
+    np.testing.assert_array_equal(out, ref)
+    assert cycles == ref_cycles
+    assert stats.tiles == ref_stats.tiles == 6
+    assert rows == [14, 14, 8]
+    assert st["native"] == 3 and st["passes"] == 6 and st["fallback"] == 0
+
+
+@pytest.mark.parametrize("engine,integrity", [
+    ("host", False), ("jit", False), ("pallas", True)])
+def test_per_tile_paths_dispatch_once_per_pass(engine, integrity):
+    """``host`` and ``jit`` (no ``layer_calls``) and the checked path on
+    ``pallas`` still make one call per plan pass."""
+    out, _, stats, st = _layer_run(
+        dict(C=4), dict(tile_pixels=7, tile_filters=2, integrity=integrity),
+        engine)
+    ref, *_ = _layer_run(dict(C=4), dict(tile_pixels=7, tile_filters=2),
+                         "host")
+    np.testing.assert_array_equal(out, ref)
+    assert stats.integrity == integrity
+    assert stats.tiles > 1
+    assert st["native"] == st["passes"] == stats.tiles
 
 
 # ---------------------------------------------------------------------------
@@ -377,6 +496,8 @@ def test_registry_capability_flags():
     assert not pal.supports_acc(16)
     assert pal.w4a4 and pal.compressed_planes and pal.integrity
     assert pal.max_lane_words is not None
+    assert pal.layer_calls
+    assert not host.layer_calls and not backends.get_backend("jit").layer_calls
     for name in backends.registered_backends():
         assert callable(backends.get_backend(name).dot_words)
 
@@ -395,7 +516,7 @@ def test_dispatch_stats_count_fallbacks():
     np.testing.assert_array_equal(np.asarray(vals), np.asarray(ref))
     st = backends.dispatch_stats()["pallas"]
     assert st == {"native": 0, "fallback": 1, "bytes_to_device": 0,
-                  "bytes_from_device": 0}
+                  "bytes_from_device": 0, "passes": 0}
 
 
 def test_pallas_on_tpu_raises_instead_of_falling_back(monkeypatch):
@@ -412,7 +533,7 @@ def test_pallas_on_tpu_raises_instead_of_falling_back(monkeypatch):
         bs.packed_dot_words(xw, ww, K=9, acc_bits=32, engine="pallas")
     assert backends.dispatch_stats()["pallas"] == {
         "native": 0, "fallback": 0, "bytes_to_device": 0,
-        "bytes_from_device": 0}
+        "bytes_from_device": 0, "passes": 0}
 
 
 def test_tpu_platform_defaults_to_pallas(monkeypatch):

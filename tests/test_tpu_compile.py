@@ -6,7 +6,8 @@ compiler, which refuses what the chip would refuse (unaligned blocks,
 primitives Mosaic cannot lower, unsupported MXU operand types).  The tile
 shapes are ones the ``inception.FULL`` schedule sends through the
 ``pallas`` backend: the stem's first conv, a ``Mixed_5b`` 1x1, a
-``Mixed_7b`` 3x3 and the FC (rows x K x filters per tile).
+``Mixed_7b`` 3x3 and the FC (rows x K x filters per tile), and the
+whole-layer calls of the backend program, up to the largest layer.
 
 The topology is described inside a module fixture, never at import time;
 the persistent compilation cache and x64 types are off around the
@@ -29,6 +30,17 @@ FULL_TILES = [
     pytest.param((1225, 192, 3), id="Mixed_5b_b0_0"),
     pytest.param((64, 4032, 3), id="Mixed_7b_b2_1"),
     pytest.param((1, 2048, 504), id="FullyConnected"),
+]
+# (rows, K, live filters) of whole layers, which the ``pallas`` backend
+# takes in one call (batch 1; the pruned network's halves beside the
+# largest, Conv2d_2a/2b: 21,609 rows padded to 32,768)
+FULL_LAYERS = [
+    pytest.param((21609, 288, 32), id="Conv2d_2a_3x3-layer"),
+    pytest.param((21609, 288, 64), id="Conv2d_2b_3x3-layer"),
+    pytest.param((22201, 27, 16), id="Conv2d_1a_3x3-layer-pruned"),
+    pytest.param((289, 896, 192), id="Mixed_6b_b1_2-layer"),
+    pytest.param((64, 4032, 384), id="Mixed_7b_b2_1-layer"),
+    pytest.param((1, 2048, 1001), id="FullyConnected-layer"),
 ]
 
 
@@ -114,11 +126,12 @@ def test_quant_matmul_compiles(one_chip, tile):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("tile", FULL_TILES)
+@pytest.mark.parametrize("tile", FULL_TILES + FULL_LAYERS)
 def test_pallas_backend_program_compiles(one_chip, tile, monkeypatch):
-    """The program the ``pallas`` backend dispatches per tile: word-grid
-    decode plus the exact unsigned kernel, at the bucketed row counts.
-    The platform check would see this CPU, so the test steers it."""
+    """The program the ``pallas`` backend dispatches per call (a plan
+    tile, or a whole layer's pass list): word-grid decode plus the exact
+    unsigned kernel, at the bucketed row counts.  The platform check
+    would see this CPU, so the test steers it."""
     from repro.core import backends
     from repro.core import bitserial as bs
     from repro.kernels import ops
