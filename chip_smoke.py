@@ -71,9 +71,9 @@ def stem_check(engine, image, names=STEM_CHECK) -> None:
     import numpy as np
 
     from repro.core import nc_layers as nc
+    from repro.core import nc_network
     from repro.core import quantize as q
     from repro.core import simulator as sim
-    from repro.models import inception
 
     specs = {s.name: s for s in engine.specs}
     plans = {p.spec.name: p for p in engine._schedule_for(1).layers}
@@ -98,9 +98,10 @@ def stem_check(engine, image, names=STEM_CHECK) -> None:
                 and np.array_equal(accs["pallas"], accs["host"])):
             raise AssertionError(f"{name}: pallas accumulators differ from "
                                  f"the host reference")
-        actq, qps = inception._nc_run_conv(
-            name, actq, qps, stem[name], engine.wpack, specs[name],
-            plans[name], engine.geom, sim.SimConstants(), "pallas", [])
+        actq, qps = nc_network._nc_run_conv(
+            name, actq, qps, stem[name], nc_network._Exec(
+                engine.wpack, specs, plans, engine.geom, sim.SimConstants(),
+                "pallas", []))
     _log(f"stem check: {', '.join(names)} byte-identical to host")
 
 

@@ -31,7 +31,7 @@ MAX_FILTER_BYTES_PER_LINE = 9  # filter splitting threshold (§IV-A)
 MAX_PACK_BYTES = 16  # 1x1 filter packing factor (§IV-A)
 MAX_REDUCE_LINES = 512  # two arrays sharing sense amps (§III-D)
 
-LayerKind = Literal["conv", "fc", "maxpool", "avgpool"]
+LayerKind = Literal["conv", "fc", "maxpool", "avgpool", "residual"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,8 +60,10 @@ class LayerSpec:
 
     @property
     def window_count(self) -> int:
-        """Pooling windows (pooling layers do comparisons, not MACs)."""
-        return self.M * self.E * self.E if self.kind in ("maxpool", "avgpool") else 0
+        """Pooling windows, or a residual join's output elements (pools
+        compare and joins add: neither does MACs)."""
+        return (self.M * self.E * self.E
+                if self.kind in ("maxpool", "avgpool", "residual") else 0)
 
     @property
     def filter_bytes(self) -> int:
@@ -161,9 +163,10 @@ class MappedLayer:
 
 
 def map_layer(spec: LayerSpec, geom: CacheGeometry = XEON_E5_35MB) -> MappedLayer:
-    if spec.kind in ("maxpool", "avgpool"):
+    if spec.kind in ("maxpool", "avgpool", "residual"):
         # pooling maps like conv but with no filters (§IV-D): window elems
         # occupy lines; comparisons happen per line-group of C channels.
+        # A residual join (R = S = 1) holds one output element per lane.
         work = spec.window_count
         c_round = min(_next_pow2(max(spec.filter_elems, 1)), MAX_REDUCE_LINES)
         per_array = max(geom.array_cols // c_round, 1)

@@ -39,7 +39,7 @@ Value sparsity is a first-class *input* to the plan, not an opportunistic
 engine trick.  A :class:`LayerOccupancy` carries what the pack-time scan
 (:func:`bitserial.filter_occupancy`, run over the quantized filter rows)
 detected, plus a ReLU-chain activation-sparsity estimate threaded from the
-model definition (models/inception.py):
+network description (core/nc_network.py):
 
 * ``zero_filters`` — filters whose every quantized weight equals the zero
   point.  Their dequantized value is exactly 0, so their whole serialized
@@ -81,7 +81,8 @@ Consumers (the "one source of truth" contract):
 * core/simulator.py prices the SAME plan instead of re-deriving residency,
   so modeled and emulated cycles agree on the layout by construction
   (skipped-pass credits included),
-* models/inception.py executes the schedule end to end (``nc_forward``),
+* core/nc_network.py executes the schedule end to end (``nc_forward``)
+  for every network description,
 * launch/serve.py admits request batches sized to the schedule, and
 * core/slo.py predicts per-batch serving latency from it (the SLO
   admission policy's control input; ``stream_batch_limit`` is its hard
@@ -662,7 +663,7 @@ def prune_occupancy(specs: Iterable[LayerSpec], fraction: float = 0.5,
     filters of every conv/fc layer as zero.
 
     The deterministic counterpart of actually zeroing weights
-    (models/inception.prune_wpack uses the same last-k rule, so a plan
+    (nc_network.prune_wpack uses the same last-k rule, so a plan
     built here matches the engine's pack-time detection on the pruned
     weights).  Used by the golden cycle-model regression and the
     dense-vs-sparse benchmarks — no weight tensors needed: skipped-pass

@@ -208,6 +208,22 @@ def simulate_layer(
     m = plan.mapped
     f_hz = geom.compute_freq_hz
 
+    if spec.kind == "residual":
+        # the in-cache join, all lanes in lockstep: each operand and the
+        # sum requantize (three fixed-point multiplies, one quant pass)
+        # around one 32-bit add; the min/max tree once per layer
+        pass_cycles = const.quant_pass_cycles + bs.add_cycles(32)
+        quant_s = (plan.serial_passes * pass_cycles
+                   + const.quant_layer_overhead_cycles) / f_hz
+        input_s = 2 * spec.output_bytes / const.input_bw  # both operands
+        output_s = spec.output_bytes / const.output_bw
+        energy = (
+            plan.serial_passes * pass_cycles * geom.compute_arrays
+            * m.utilization * geom.compute_energy_pj * 1e-12
+        )
+        return LayerResult(spec, m, 0.0, 0.0, quant_s, 0.0, 0.0, input_s,
+                           output_s, pass_cycles, energy, plan)
+
     if spec.kind in ("maxpool", "avgpool"):
         # window_size-1 comparisons per window, all lanes in lockstep
         cmps = max(spec.filter_elems - 1, 1)

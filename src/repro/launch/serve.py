@@ -16,9 +16,11 @@ The Neural Cache path (:class:`NCServingEngine`) serves the paper's
 workload the paper's way (§VI-C): admitted image requests form one batch
 that streams through the reserved I/O way while the filters stay resident
 — the engine plans a :class:`~repro.core.schedule.NetworkSchedule` once
-per batch size and routes every admitted batch through
-``models.inception.nc_forward(batch=N)`` (batch folded into the packed
-lane axis, in-cache §IV-D min/max quantization, bucketed-jit engine).
+per batch size and routes every admitted batch through its network's
+``nc_forward`` (Inception v3 by default, ResNet-50 v1.5 with
+``network=models.resnet``; both run the shared executor of
+core/nc_network.py: batch folded into the packed lane axis, in-cache
+§IV-D min/max quantization).
 
 With ``--slo-ms`` the engine turns SLO-aware (core/slo.py): a
 :class:`~repro.core.slo.LatencyModel` built over the SAME per-batch-size
@@ -226,8 +228,8 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
     """Batched Neural Cache inference server.
 
     Each ``step()`` admits up to ``max_batch`` queued images and executes
-    them as ONE batched forward through the bit-serial emulation
-    (``models.inception.nc_forward``): the batch folds into the packed
+    them as ONE batched forward through the bit-serial emulation (the
+    network's ``nc_forward``): the batch folds into the packed
     lane axis, filters pack once per layer per batch, and quantization
     ranges come from the in-cache min/max tree — the serving half of the
     paper's 604 inf/s headline (§VI-C).  The per-layer tiling comes from a
@@ -235,8 +237,15 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
     size (ragged final batches plan-and-cache their own), so the mapper,
     the packed engine and the server all execute the same plan object.
 
+    ``network`` is the model module whose network the engine serves:
+    ``models.inception`` (the default) or ``models.resnet``.  Its
+    ``specs``, ``prepare_conv_weights``, ``network_occupancy``,
+    ``observed_occupancy``, ``nc_forward`` and float ``apply`` (the
+    recovery ladder's last rung) are what the engine calls, and its
+    ``REDUCED`` is the default ``config``.
+
     ``sparse=True`` (the default) plans against the deployed weights'
-    detected value sparsity (``inception.network_occupancy``): serialized
+    detected value sparsity (``network.network_occupancy``): serialized
     passes of all-zero (pruned) filters are dropped from every batch's
     schedule, with logits byte-identical to dense execution — a deployment
     serving an EIE-style pruned model gets the cycle and wall-time win for
@@ -275,7 +284,7 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
     ``warmup_replan=True`` (PR 8) treats the first successfully served
     batch as a measurement: its report's observed per-layer input
     sparsity and live output bytes replace the advisory ReLU-chain
-    estimate (``inception.observed_occupancy``), every cached plan is
+    estimate (``network.observed_occupancy``), every cached plan is
     rebuilt from the measured occupancy (requant passes shrink to the
     live output set), and the latency model drops its priced results so
     the calibration curve never mixes estimate-planned and
@@ -305,7 +314,8 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
     ``degraded_batches``/``calibration_excluded``).
     """
 
-    def __init__(self, params, config=None, *, max_batch: int = 4,
+    def __init__(self, params, config=None, *, network=None,
+                 max_batch: int = 4,
                  geom=None, engine: str | None = None, sparse: bool = True,
                  overlap: bool = True, integrity: bool = False,
                  compressed: bool = False, warmup_replan: bool = False,
@@ -315,13 +325,15 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
         from repro.core import schedule as nc_schedule
         from repro.core import slo as nc_slo
         from repro.core.cache_geometry import XEON_E5_35MB
-        from repro.models import inception
+
+        if network is None:
+            from repro.models import inception as network
 
         super().__init__()
         self.name = name
-        self._inception = inception
+        self.network = network
         self._plan_network = nc_schedule.plan_network
-        self.config = config or inception.REDUCED
+        self.config = config or network.REDUCED
         self.params = params
         self.max_batch = max_batch
         self.geom = geom or XEON_E5_35MB
@@ -332,11 +344,11 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
             engine = nc_backends.get_backend(engine).name
         self.engine = engine
         self.now_fn = now_fn
-        self.specs = inception.inception_v3_specs(self.config)
+        self.specs = network.specs(self.config)
         # resident filters quantize ONCE per deployment, not once per batch;
         # the occupancy scan runs on the same resident weights
-        self.wpack = inception.prepare_conv_weights(params, self.config)
-        self.occupancy = (inception.network_occupancy(self.wpack, self.config)
+        self.wpack = network.prepare_conv_weights(params, self.config)
+        self.occupancy = (network.network_occupancy(self.wpack, self.config)
                           if sparse else None)
         self.overlap = overlap
         self.integrity = integrity
@@ -388,13 +400,13 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
     def _replan_from_report(self, report) -> None:
         """Warmup re-planning (PR 8): replace the advisory ReLU-chain
         occupancy estimate with what the warmup batch MEASURED —
-        ``inception.observed_occupancy`` re-scans the resident filters and
+        ``network.observed_occupancy`` re-scans the resident filters and
         takes each conv's input sparsity and live output bytes from the
         report — then drop every cached plan and the latency model's
         priced results so subsequent batches plan, execute and are
         predicted from the measured occupancy.  The dense/serial fallback
         plans never depended on occupancy, so they stay."""
-        self.occupancy = self._inception.observed_occupancy(
+        self.occupancy = self.network.observed_occupancy(
             self.wpack, self.config, report)
         self._schedules.clear()
         self.schedule = self._schedule_for(self.max_batch)
@@ -430,7 +442,7 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
     def _forward(self, x: np.ndarray, schedule):
         """One batched forward through the planned emulation (the seam the
         recovery ladder — and fault tests — route every attempt through)."""
-        return self._inception.nc_forward(
+        return self.network.nc_forward(
             self.params, x, config=self.config, geom=self.geom,
             engine=self.engine, schedule=schedule, wpack=self.wpack)
 
@@ -579,8 +591,8 @@ class NCServingEngine(BatchQueueEngine, _EngineAPI):
         # batch, never come back as a float answer
         if not ops.on_tpu():
             try:
-                logits = np.asarray(self._inception.apply(
-                    self.params, jnp.asarray(x, jnp.float32), quant=False,
+                logits = np.asarray(self.network.apply(
+                    self.params, jnp.asarray(x, jnp.float32),
                     config=self.config))
                 return logits, None, "float"
             except Exception as e:  # noqa: BLE001

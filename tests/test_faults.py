@@ -529,7 +529,7 @@ def test_nc_engine_unreclaimable_batch_fails_and_drains(tiny):
         raise RuntimeError("emulation down")
 
     eng._forward = broken
-    eng._inception = types.SimpleNamespace(
+    eng.network = types.SimpleNamespace(
         apply=lambda *a, **kw: (_ for _ in ()).throw(
             RuntimeError("float down")))
     done = eng.run()

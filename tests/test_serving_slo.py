@@ -422,7 +422,7 @@ def test_rung4_failed_batch_slo_accounting(tiny):
         raise RuntimeError("emulation down")
 
     eng._forward = broken
-    eng._inception = types.SimpleNamespace(
+    eng.network = types.SimpleNamespace(
         apply=lambda *a, **kw: (_ for _ in ()).throw(
             RuntimeError("float down")))
     done = eng.run()

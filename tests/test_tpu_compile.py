@@ -7,7 +7,8 @@ primitives Mosaic cannot lower, unsupported MXU operand types).  The tile
 shapes are ones the ``inception.FULL`` schedule sends through the
 ``pallas`` backend: the stem's first conv, a ``Mixed_5b`` 1x1, a
 ``Mixed_7b`` 3x3 and the FC (rows x K x filters per tile), and the
-whole-layer calls of the backend program, up to the largest layer.
+whole-layer calls of the backend program, up to the largest layer, of
+Inception and of ``resnet.FULL``.
 
 The topology is described inside a module fixture, never at import time;
 the persistent compilation cache and x64 types are off around the
@@ -41,6 +42,16 @@ FULL_LAYERS = [
     pytest.param((289, 896, 192), id="Mixed_6b_b1_2-layer"),
     pytest.param((64, 4032, 384), id="Mixed_7b_b2_1-layer"),
     pytest.param((1, 2048, 1001), id="FullyConnected-layer"),
+]
+# (rows, K, filters) of whole layers of ``resnet.FULL`` at batch 1: the
+# 7x7/2 stem (K = 147), a 1x1 at K = 64, the 56 px 3x3, the deepest 3x3
+# and the classifier
+RESNET_LAYERS = [
+    pytest.param((12544, 147, 64), id="resnet-conv1-layer"),
+    pytest.param((3136, 64, 64), id="resnet-block1_unit1_conv1-layer"),
+    pytest.param((3136, 576, 64), id="resnet-block1_unit1_conv2-layer"),
+    pytest.param((49, 4608, 512), id="resnet-block4_unit2_conv2-layer"),
+    pytest.param((1, 2048, 1000), id="resnet-FullyConnected-layer"),
 ]
 
 
@@ -126,7 +137,7 @@ def test_quant_matmul_compiles(one_chip, tile):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("tile", FULL_TILES + FULL_LAYERS)
+@pytest.mark.parametrize("tile", FULL_TILES + FULL_LAYERS + RESNET_LAYERS)
 def test_pallas_backend_program_compiles(one_chip, tile, monkeypatch):
     """The program the ``pallas`` backend dispatches per call (a plan
     tile, or a whole layer's pass list): word-grid decode plus the exact

@@ -2,16 +2,20 @@
 
 One Inception-style split op (a conv branch through the ``pallas``
 backend, run by the Pallas interpreter here, beside a max-pool branch,
-then the concat) and one serving step run inside ``jax.profiler.trace``;
-the tests read the ``.xplane.pb`` it writes.  Stage spans are leaves:
-they never nest in each other, every one of them appears, and together
-they cover most of a conv layer's ``nc.layer`` span.
+then the concat), one ResNet-style residual op (a linear conv body
+joined to the identity) and one serving step run inside
+``jax.profiler.trace``; the tests read the ``.xplane.pb`` each writes.
+Stage spans are leaves: they never nest in each other, every one of them
+appears, and together they cover most of a conv layer's ``nc.layer``
+span.  The join's ``nc.residual`` sits in its own ``nc.layer`` and in no
+stage span.
 """
 import jax
 import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+from repro.core import nc_network
 from repro.core import quantize as q
 from repro.core import schedule as sched
 from repro.core import simulator as sim
@@ -53,7 +57,7 @@ def _spans(trace_dir):
 @pytest.fixture(scope="module")
 def split_spans(x32, tmp_path_factory):
     specs = []
-    inception._op_specs("Mixed_t", "Mixed_t", SPLIT, 9, 8, specs)
+    nc_network._op_specs("Mixed_t", "Mixed_t", SPLIT, 9, 8, specs)
     plans = {p.spec.name: p for p in sched.plan_network(
         [s for s in specs if s.kind == "conv"], XEON_E5_35MB).layers}
     rng = np.random.default_rng(0)
@@ -64,12 +68,56 @@ def split_spans(x32, tmp_path_factory):
     qps = [q.QuantParams(scale=np.float32(1 / 255), zero_point=0)]
     trace_dir = tmp_path_factory.mktemp("split")
     with jax.profiler.trace(str(trace_dir)):
-        yq, _ = inception._nc_apply_op(
-            actq, qps, "Mixed_t", SPLIT, wpack, {s.name: s for s in specs},
-            plans, XEON_E5_35MB, sim.SimConstants(), "pallas", [],
-            {"concat_requant_cycles": 0})
+        yq, _ = nc_network._nc_apply_op(
+            actq, qps, "Mixed_t", SPLIT, nc_network._Exec(
+                wpack, {s.name: s for s in specs}, plans, XEON_E5_35MB,
+                sim.SimConstants(), "pallas", []))
     assert yq.shape == (1, 9, 9, 16)
     return _spans(trace_dir)
+
+
+RESIDUAL = ("residual", [("conv", ("conv", 3, 3, 8, 1, "SAME", "linear"))],
+            [])
+JOIN = "Unit_t_add"
+
+
+@pytest.fixture(scope="module")
+def residual_spans(x32, tmp_path_factory):
+    specs = []
+    nc_network._op_specs("Unit_t", "Unit_t", RESIDUAL, 9, 8, specs)
+    plans = {p.spec.name: p
+             for p in sched.plan_network(specs, XEON_E5_35MB).layers}
+    rng = np.random.default_rng(1)
+    wq = rng.integers(0, 256, size=(3, 3, 8, 8)).astype(np.uint8)
+    w_qp = q.QuantParams(scale=np.float32(0.01), zero_point=128)
+    wpack = {"Unit_t_conv": (wq, w_qp, np.zeros(8, np.float32))}
+    actq = rng.integers(0, 256, size=(1, 9, 9, 8)).astype(np.uint8)
+    qps = [q.QuantParams(scale=np.float32(1 / 255), zero_point=0)]
+    records = []
+    trace_dir = tmp_path_factory.mktemp("residual")
+    with jax.profiler.trace(str(trace_dir)):
+        yq, _ = nc_network._nc_apply_op(
+            actq, qps, "Unit_t", RESIDUAL, nc_network._Exec(
+                wpack, {s.name: s for s in specs}, plans, XEON_E5_35MB,
+                sim.SimConstants(), "host", records))
+    assert yq.shape == (1, 9, 9, 8)
+    assert [r.kind for r in records] == ["conv", "residual"]
+    return _spans(trace_dir)
+
+
+def test_residual_join_span_sits_in_its_layer_span(residual_spans):
+    [(thread, lo, hi, stats)] = [(t, s, e, st)
+                                 for t, name, s, e, st in residual_spans
+                                 if name == "nc.residual"]
+    assert stats["layer"] == JOIN
+    [(l_thread, l_lo, l_hi)] = [
+        (t, s, e) for t, name, s, e, st in residual_spans
+        if name == "nc.layer" and st["layer"] == JOIN]
+    assert l_thread == thread and l_lo <= lo and hi <= l_hi
+    # no stage span holds it, and none opens inside it
+    for t, name, s, e, _ in residual_spans:
+        if name in STAGES and t == thread:
+            assert e <= lo or s >= hi, name
 
 
 def test_every_stage_span_appears(split_spans):
@@ -108,7 +156,7 @@ def test_serving_step_span_names_its_requests(tmp_path):
     params = {name: {"w": np.full((r, s, c, m), 0.01, np.float32),
                      "scale": np.ones(m, np.float32),
                      "bias": np.zeros(m, np.float32)}
-              for name, r, s, c, m in inception._iter_convs(cfg)}
+              for name, r, s, c, m in nc_network.iter_convs(cfg)}
     eng = NCServingEngine(params, cfg, max_batch=2, engine="host")
     eng._forward = lambda x, schedule: (np.zeros((len(x), 8)), None)
     image = np.zeros((cfg.img, cfg.img, 3), np.float32)
