@@ -112,7 +112,11 @@ class Backend:
     pass list: ``nc_conv2d``'s unchecked loop then gathers every row
     tile's packed windows and every filter tile's packed columns and
     dispatches once per layer (more calls only where an operand grid
-    would pass ``max_lane_words``), instead of once per plan tile."""
+    would pass ``max_lane_words``), instead of once per plan tile.
+
+    ``compiled_pools`` declares a device path: ``nc_maxpool2d`` and
+    ``nc_avgpool2d`` run as one compiled reduce-window call on it, and
+    ``host`` keeps their bit-serial walk as the byte-exact reference."""
 
     name: str
     # accumulator widths executed natively (None = any)
@@ -123,6 +127,7 @@ class Backend:
     # cap on one operand's word-grid size (None = unbounded)
     max_lane_words: int | None
     layer_calls: bool  # takes a layer's whole pass list in one call
+    compiled_pools: bool  # pools run as one compiled call, not the walk
     dot_words: Callable[..., np.ndarray]
 
     def supports_acc(self, acc_bits: int) -> bool:
@@ -368,12 +373,12 @@ def _pallas_dot_words(xw, ww, *, K: int, acc_bits: int,
 register_backend(Backend(
     name="host", acc_bits=None, w4a4=True, compressed_planes=True,
     integrity=True, max_lane_words=None, layer_calls=False,
-    dot_words=_host_dot_words))
+    compiled_pools=False, dot_words=_host_dot_words))
 register_backend(Backend(
     name="jit", acc_bits=None, w4a4=True, compressed_planes=True,
     integrity=True, max_lane_words=None, layer_calls=False,
-    dot_words=_jit_dot_words))
+    compiled_pools=True, dot_words=_jit_dot_words))
 register_backend(Backend(
     name="pallas", acc_bits=(24, 32), w4a4=True,
     compressed_planes=True, integrity=True, max_lane_words=1 << 22,
-    layer_calls=True, dot_words=_pallas_dot_words))
+    layer_calls=True, compiled_pools=True, dot_words=_pallas_dot_words))

@@ -52,11 +52,13 @@ The TPU-fast path lives in repro/kernels.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.profiler import TraceAnnotation
 
 from repro.core import backends as _backends
@@ -825,14 +827,72 @@ def nc_conv2d(
     return result, total_cycles, stats
 
 
+def _pool_cycles(kind: str, B: int, E: int, F: int, window: int):
+    """Emulated cycles of a ``kind`` ("max" or "avg") pool with B x E x F
+    output lane groups: the closed forms the bit-serial walk asserts step
+    by step (one subtract + masked copy per max step; the widening log
+    tree plus one 8-bit divide per average)."""
+    w2 = window * window
+    if kind == "max":
+        return (w2 - 1) * (bs.add_cycles(8) + 8 + 1) * B * E * F
+    return int(B * E * F * (bs.reduce_cycles(w2, 8) + bs.div_cycles(8)))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("kind", "window", "stride", "padding"))
+def _pool_device(x, *, kind: str, window: int, stride: int, padding: str):
+    """One compiled reduce-window over a ``[B, H, W, C]`` uint8 batch,
+    byte-identical to the bit-serial walk: max pads with 0 (the uint8
+    minimum); the average divides the int32 window sum by the
+    pad-excluded population, rounded."""
+    _, H, W, _ = x.shape
+    pads = ((0, 0), (0, 0), (0, 0), (0, 0))
+    if padding == "SAME":
+        pads = ((0, 0), _same_pad(H, window, stride),
+                _same_pad(W, window, stride), (0, 0))
+    dims, strides = (1, window, window, 1), (1, stride, stride, 1)
+    op = lax.max if kind == "max" else lax.add
+    out = lax.reduce_window(x.astype(jnp.int32), np.int32(0), op, dims,
+                            strides, pads)
+    if kind == "avg":
+        counts = lax.reduce_window(jnp.ones((1, H, W, 1), jnp.int32),
+                                   np.int32(0), lax.add, dims, strides, pads)
+        # (sum + count // 2) // count: an int32 divide by an array costs
+        # the TPU compiler about a minute per shape, so the float32
+        # quotient is taken and corrected by one step to the exact floor
+        num = out + counts // 2
+        quo = jnp.floor(num.astype(jnp.float32)
+                        / counts.astype(jnp.float32)).astype(jnp.int32)
+        quo = quo - (quo * counts > num) + ((quo + 1) * counts <= num)
+        out = jnp.clip(quo, 0, 255)
+    return out.astype(jnp.uint8)
+
+
+def _pool_compiled(kind: str, x_q, window: int, stride: int, padding: str):
+    """The pool as one device call: the uint8 activation goes to the
+    device, :func:`_pool_device` reduces it, the cycles come from
+    :func:`_pool_cycles`."""
+    x = jnp.asarray(x_q, jnp.uint8)
+    batched = x.ndim == 4
+    out = _pool_device(x if batched else x[None], kind=kind, window=window,
+                       stride=stride, padding=padding)
+    B, E, F, _ = out.shape
+    return (out if batched else out[0]), _pool_cycles(kind, B, E, F, window)
+
+
 def nc_maxpool2d(x_q: jax.Array, window: int, stride: int,
-                 padding: str = "VALID"):
+                 padding: str = "VALID", engine: str | None = None):
     """uint8 max pooling via subtract + MSB-masked copies (§IV-D).
 
     Accepts ``[H, W, C]`` or ``[B, H, W, C]``; all B x E x F x C output
     lanes advance in lockstep through the window^2 - 1 sequential max
     steps (cycle count stays per-pixel, as the per-pixel formulation
-    reported it)."""
+    reported it).  On a backend with ``compiled_pools`` (``engine``) the
+    values come from one compiled reduce-window call and the cycles from
+    the walk's closed form; without an engine, or on ``host``, the
+    bit-serial walk runs."""
+    if engine is not None and _backends.get_backend(engine).compiled_pools:
+        return _pool_compiled("max", x_q, window, stride, padding)
     xin = np.asarray(x_q, np.int64)
     batched = xin.ndim == 4
     xq = xin if batched else xin[None]
@@ -855,7 +915,7 @@ def nc_maxpool2d(x_q: jax.Array, window: int, stride: int,
 
 
 def nc_avgpool2d(x_q: jax.Array, window: int, stride: int,
-                 padding: str = "VALID"):
+                 padding: str = "VALID", engine: str | None = None):
     """uint8 average pooling: in-array window-sum via the §III-D log tree,
     then the §III-C bit-serial divide (rounded; SAME padding divides by the
     pad-excluded window population, matching the float reference — exact
@@ -863,7 +923,10 @@ def nc_avgpool2d(x_q: jax.Array, window: int, stride: int,
     every post-ReLU activation in the §IV-D pipeline).
 
     Accepts ``[H, W, C]`` or ``[B, H, W, C]``.  Cycles per output lane
-    group: the widening sum tree over the window plus one 8-bit divide."""
+    group: the widening sum tree over the window plus one 8-bit divide.
+    ``engine`` selects the compiled call as in :func:`nc_maxpool2d`."""
+    if engine is not None and _backends.get_backend(engine).compiled_pools:
+        return _pool_compiled("avg", x_q, window, stride, padding)
     xin = np.asarray(x_q, np.int64)
     batched = xin.ndim == 4
     xq = xin if batched else xin[None]

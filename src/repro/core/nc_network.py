@@ -512,11 +512,10 @@ def _nc_run_conv(name, actq, act_qps, op, ex: _Exec):
 
 def _nc_run_pool(name, actq, act_qps, op, ex: _Exec):
     kind, r, stride, pad = op
-    with TraceAnnotation("nc.pool"):
-        if kind == "maxpool":
-            out_q, cycles = nc.nc_maxpool2d(actq, r, stride, padding=pad)
-        else:
-            out_q, cycles = nc.nc_avgpool2d(actq, r, stride, padding=pad)
+    pool = nc.nc_maxpool2d if kind == "maxpool" else nc.nc_avgpool2d
+    compiled = _backends.get_backend(ex.engine).compiled_pools
+    with TraceAnnotation("nc.pool", path="compiled" if compiled else "walk"):
+        out_q, cycles = pool(actq, r, stride, padding=pad, engine=ex.engine)
         out_q = np.asarray(out_q, np.uint8)
     with TraceAnnotation("nc.accounting"):
         # never skip
@@ -810,7 +809,9 @@ def nc_forward(params: dict, x: jax.Array, config,
     call is one ``nc.forward``; each conv, pool, residual join and the FC
     one ``nc.layer`` with a ``layer`` stat naming it; inside them the
     host stages ``nc.conv.epilogue`` (bias, ReLU, min/max tree, requant),
-    ``nc.pool``, ``nc.residual`` (the join, with the ``layer`` stat) and
+    ``nc.pool`` (with the ``path`` stat: ``compiled`` on a device
+    backend, ``walk`` on ``host``), ``nc.residual`` (the join, with the
+    ``layer`` stat) and
     ``nc.accounting`` (modeled cycles and the report), and ``nc.concat``
     at each branch concatenation.  docs/SERVING.md lists every span.
     """

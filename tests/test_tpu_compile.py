@@ -8,7 +8,8 @@ shapes are ones the ``inception.FULL`` schedule sends through the
 ``pallas`` backend: the stem's first conv, a ``Mixed_5b`` 1x1, a
 ``Mixed_7b`` 3x3 and the FC (rows x K x filters per tile), and the
 whole-layer calls of the backend program, up to the largest layer, of
-Inception and of ``resnet.FULL``.
+Inception and of ``resnet.FULL``; beside them the compiled pool call of
+the device backends at the pools' shapes.
 
 The topology is described inside a module fixture, never at import time;
 the persistent compilation cache and x64 types are off around the
@@ -52,6 +53,20 @@ RESNET_LAYERS = [
     pytest.param((3136, 576, 64), id="resnet-block1_unit1_conv2-layer"),
     pytest.param((49, 4608, 512), id="resnet-block4_unit2_conv2-layer"),
     pytest.param((1, 2048, 1000), id="resnet-FullyConnected-layer"),
+]
+
+# (kind, [B, H, W, C], window, stride, padding) of the pools of
+# ``inception.FULL`` and ``resnet.FULL``, at batch 1 and 4
+FULL_POOLS = [
+    pytest.param(("max", (1, 147, 147, 64), 3, 2, "VALID"),
+                 id="MaxPool_3a_3x3"),
+    pytest.param(("avg", (1, 35, 35, 192), 3, 1, "SAME"), id="Mixed_5b_b3_0"),
+    pytest.param(("avg", (4, 17, 17, 768), 3, 1, "SAME"),
+                 id="Mixed_6b_b3_0-b4"),
+    pytest.param(("avg", (1, 8, 8, 2048), 8, 1, "VALID"), id="AvgPool"),
+    pytest.param(("max", (1, 112, 112, 64), 3, 2, "SAME"), id="resnet-pool1"),
+    pytest.param(("avg", (4, 7, 7, 2048), 7, 1, "VALID"),
+                 id="resnet-AvgPool-b4"),
 ]
 
 
@@ -155,3 +170,15 @@ def test_pallas_backend_program_compiles(one_chip, tile, monkeypatch):
         _s(one_chip, (8, bs.bucket_words(M), wpr), jnp.uint32),
         _s(one_chip, (8, bs.bucket_words(N), wpr), jnp.uint32))
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("pool", FULL_POOLS)
+def test_compiled_pool_compiles(one_chip, pool):
+    """The one reduce-window program a device backend runs per pool."""
+    from repro.core import nc_layers
+
+    kind, shape, window, stride, padding = pool
+    txt = nc_layers._pool_device.lower(
+        _s(one_chip, shape, jnp.uint8), kind=kind, window=window,
+        stride=stride, padding=padding).compile().as_text()
+    assert "reduce" in txt

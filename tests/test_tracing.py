@@ -3,12 +3,13 @@
 One Inception-style split op (a conv branch through the ``pallas``
 backend, run by the Pallas interpreter here, beside a max-pool branch,
 then the concat), one ResNet-style residual op (a linear conv body
-joined to the identity) and one serving step run inside
+joined to the identity), one pool op per backend and one serving step
+run inside
 ``jax.profiler.trace``; the tests read the ``.xplane.pb`` each writes.
 Stage spans are leaves: they never nest in each other, every one of them
 appears, and together they cover most of a conv layer's ``nc.layer``
 span.  The join's ``nc.residual`` sits in its own ``nc.layer`` and in no
-stage span.
+stage span.  ``nc.pool`` names the path its pool took.
 """
 import jax
 import numpy as np
@@ -147,6 +148,26 @@ def test_stage_spans_cover_half_of_a_conv_layer(split_spans):
                   for t, name, s, e, _ in split_spans
                   if name in STAGES and t == thread and s < hi and e > lo)
     assert covered >= 0.5 * (hi - lo)
+
+
+@pytest.mark.parametrize("engine,path", [
+    ("host", "walk"), ("jit", "compiled"), ("pallas", "compiled")])
+def test_pool_span_names_its_path(x32, tmp_path, engine, path):
+    op = ("maxpool", 3, 2, "SAME")
+    specs = []
+    nc_network._op_specs("Pool_t", "Pool_t", op, 9, 8, specs)
+    actq = np.random.default_rng(2).integers(
+        0, 256, size=(1, 9, 9, 8)).astype(np.uint8)
+    qps = [q.QuantParams(scale=np.float32(1 / 255), zero_point=0)]
+    with jax.profiler.trace(str(tmp_path)):
+        yq, _ = nc_network._nc_apply_op(
+            actq, qps, "Pool_t", op, nc_network._Exec(
+                {}, {s.name: s for s in specs}, {}, XEON_E5_35MB,
+                sim.SimConstants(), engine, []))
+    assert yq.shape == (1, 5, 5, 8)
+    [stats] = [st for _, name, *_, st in _spans(tmp_path)
+               if name == "nc.pool"]
+    assert stats["path"] == path
 
 
 def test_serving_step_span_names_its_requests(tmp_path):
