@@ -145,6 +145,7 @@ def _sparsity_rows():
     import time
 
     import jax as _jax
+    from repro.core import schedule as nc_sched
     from repro.models import inception
 
     cfg = inception.reduced_config()
@@ -153,6 +154,7 @@ def _sparsity_rows():
         inception.prepare_conv_weights(params, cfg), 0.5)
     xb = np.asarray(_jax.random.uniform(
         _jax.random.PRNGKey(1), (4, cfg.img, cfg.img, 3), jnp.float32))
+    specs = inception.specs(cfg)
 
     # interleaved min-of-3 (first pass also warms the bucketed-jit engine
     # caches): the host_noise drift hits dense and sparse alike, and the
@@ -166,8 +168,11 @@ def _sparsity_rows():
                                                wpack=wpack)
         wall_d = min(wall_d, time.perf_counter() - t0)
         t0 = time.perf_counter()
-        logits_s, rep_s = inception.nc_forward(params, xb, config=cfg,
-                                               wpack=wpack, sparse=True)
+        logits_s, rep_s = inception.nc_forward(
+            params, xb, config=cfg, wpack=wpack,
+            schedule=nc_sched.plan_network(
+                specs, batch=4,
+                occupancy=inception.network_occupancy(wpack, cfg)))
         wall_s = min(wall_s, time.perf_counter() - t0)
     if not np.array_equal(np.asarray(logits_d), np.asarray(logits_s)):
         raise RuntimeError("sparsity gate: sparse nc_forward logits diverge "
@@ -250,7 +255,7 @@ def _kernel_rows():
 def _overlap_rows():
     """Serial-vs-overlapped record pair: a batch-4 reduced config executed
     through the PR 3/4 serial plan and through the double-buffered plan
-    (``nc_forward(..., overlap=True)``: pass k+1's packed filter columns
+    (``plan_network(..., overlap=True)``: pass k+1's packed filter columns
     prefetch while pass k's MAC+reduce runs).  The workload is the stem at
     ``width_div=2`` on a ``scaled(4)`` geometry — at the full 35 MB array
     every reduced-config layer is single-pass and the §IV-E legality rule
@@ -272,6 +277,7 @@ def _overlap_rows():
 
     import jax as _jax
     from benchmarks.common import overlap_wall_slack
+    from repro.core import schedule as nc_sched
     from repro.core.cache_geometry import XEON_E5_35MB
     from repro.models import inception
 
@@ -281,6 +287,13 @@ def _overlap_rows():
     wpack = inception.prepare_conv_weights(params, cfg)
     xb = np.asarray(_jax.random.uniform(
         _jax.random.PRNGKey(1), (4, cfg.img, cfg.img, 3), jnp.float32))
+    specs = inception.specs(cfg)
+
+    def plan(wp=None, **decisions):
+        """The batch-4 schedule with the weights ``wp``'s occupancy."""
+        occ = None if wp is None else inception.network_occupancy(wp, cfg)
+        return nc_sched.plan_network(specs, geom, batch=4, occupancy=occ,
+                                     **decisions)
 
     wall_s = wall_o = float("inf")
     logits_srl = logits_ov = None
@@ -293,7 +306,7 @@ def _overlap_rows():
         t0 = time.perf_counter()
         logits_ov, rep_o = inception.nc_forward(params, xb, config=cfg,
                                                 geom=geom, wpack=wpack,
-                                                overlap=True)
+                                                schedule=plan(overlap=True))
         wall_o = min(wall_o, time.perf_counter() - t0)
     if not np.array_equal(np.asarray(logits_srl), np.asarray(logits_ov)):
         raise RuntimeError("overlap gate: overlapped nc_forward logits "
@@ -326,12 +339,13 @@ def _overlap_rows():
     for _ in range(3):
         t0 = time.perf_counter()
         logits_ps, _ = inception.nc_forward(params, xb, config=cfg,
-                                            geom=geom, wpack=wp, sparse=True)
+                                            geom=geom, wpack=wp,
+                                            schedule=plan(wp))
         wall_ps = min(wall_ps, time.perf_counter() - t0)
         t0 = time.perf_counter()
         logits_po, _ = inception.nc_forward(params, xb, config=cfg,
-                                            geom=geom, wpack=wp, sparse=True,
-                                            overlap=True)
+                                            geom=geom, wpack=wp,
+                                            schedule=plan(wp, overlap=True))
         wall_po = min(wall_po, time.perf_counter() - t0)
     if not np.array_equal(np.asarray(logits_ps), np.asarray(logits_po)):
         raise RuntimeError("overlap gate: sparse+overlap logits diverge "
@@ -396,9 +410,12 @@ def _compressed_rows():
                                            wpack=wpack)
         wall_d = min(wall_d, time.perf_counter() - t0)
         t0 = time.perf_counter()
-        logits_c, rep_c = inception.nc_forward(params, xb, config=cfg,
-                                               wpack=wpack, sparse=True,
-                                               compressed=True)
+        logits_c, rep_c = inception.nc_forward(
+            params, xb, config=cfg, wpack=wpack,
+            schedule=nc_sched.plan_network(
+                specs, XEON_E5_35MB, batch=4,
+                occupancy=inception.network_occupancy(wpack, cfg),
+                compressed=True))
         wall_c = min(wall_c, time.perf_counter() - t0)
     if not np.array_equal(np.asarray(logits_d), np.asarray(logits_c)):
         raise RuntimeError("compression gate: CSR-store nc_forward logits "
@@ -427,6 +444,8 @@ def _compressed_smoke_rows():
     dense store.  Subsecond, registers a retimer like the kernel rows."""
     from repro.core import nc_layers as nc
     from repro.core import quantize as q
+    from repro.core import schedule as nc_sched
+    from repro.core.mapper import LayerSpec
 
     rng = np.random.default_rng(0)
     wq = rng.integers(0, 256, size=(3, 3, 4, 16)).astype(np.uint8)
@@ -434,16 +453,25 @@ def _compressed_smoke_rows():
     w_qp = q.QuantParams(scale=np.float32(0.05), zero_point=7)
     x = rng.uniform(-1, 1, (2, 10, 10, 4)).astype(np.float32)
     x_qp = q.choose_qparams(jnp.float32(x.min()), jnp.float32(x.max()))
+    spec = LayerSpec(name="nc_conv2d", kind="conv", H=12, R=3, S=3, C=4,
+                     M=16, E=10)
+
+    def csr_conv():
+        """The conv under its detected occupancy and the CSR store."""
+        occ = nc_sched.LayerOccupancy.from_filter_rows(
+            wq.reshape(-1, 16).T, w_qp.bits, 7)
+        plan = nc_sched.plan_layer(spec, batch=2, occupancy=occ,
+                                   compressed=True)
+        return nc.nc_conv2d(x, wq, [x_qp] * 2, w_qp, padding="SAME",
+                            plan=plan)
+
     dense, _ = nc.nc_conv2d(x, wq, [x_qp] * 2, w_qp, padding="SAME")
-    comp, _ = nc.nc_conv2d(x, wq, [x_qp] * 2, w_qp, padding="SAME",
-                           occupancy="detect", compressed=True)
+    comp, _ = csr_conv()
     if not np.array_equal(np.asarray(comp), np.asarray(dense)):
         raise RuntimeError("compression smoke gate: CSR-store conv diverges "
                            "from the dense store")
     return [_timed_rec(
-        "emulation/csr_conv_smoke",
-        lambda: nc.nc_conv2d(x, wq, [x_qp] * 2, w_qp, padding="SAME",
-                             occupancy="detect", compressed=True), 5,
+        "emulation/csr_conv_smoke", csr_conv, 5,
         "2x 10x10x4 * 3x3x4x16, 50% pruned",
         "CSR bit-plane store, byte-identical to dense")]
 
@@ -468,6 +496,7 @@ def _backend_rows():
     from repro.core import backends as nc_backends
     from repro.core import bitserial as bs
     from repro.core import nc_layers as nc
+    from repro.core import schedule as nc_sched
     from repro.models import inception
 
     cfg = inception.reduced_config()
@@ -484,8 +513,10 @@ def _backend_rows():
         for name in ("host", "jit"):
             t0 = time.perf_counter()
             logits[name], reports[name] = inception.nc_forward(
-                params, xb, config=cfg, wpack=wpack, sparse=True,
-                engine=name)
+                params, xb, config=cfg, wpack=wpack, engine=name,
+                schedule=nc_sched.plan_network(
+                    inception.specs(cfg), batch=4,
+                    occupancy=inception.network_occupancy(wpack, cfg)))
             walls[name] = min(walls[name], time.perf_counter() - t0)
     if not np.array_equal(np.asarray(logits["host"]),
                           np.asarray(logits["jit"])):
@@ -555,7 +586,9 @@ def _fault_rows():
 
     from repro.core import faults, nc_layers as nc
     from repro.core import quantize as q
+    from repro.core import schedule as nc_sched
     from repro.core.cache_geometry import XEON_E5_35MB
+    from repro.core.mapper import LayerSpec
 
     rng = np.random.default_rng(0)
     geom = XEON_E5_35MB
@@ -564,6 +597,10 @@ def _fault_rows():
     x_qp = q.choose_qparams(jnp.float32(x.min()), jnp.float32(x.max()))
     w_qp = q.choose_qparams(jnp.float32(w.min()), jnp.float32(w.max()))
 
+    checked = nc_sched.plan_layer(
+        LayerSpec(name="nc_conv2d", kind="conv", H=12, R=3, S=3, C=4, M=16,
+                  E=10), geom, batch=2, integrity=True)
+
     def conv(**kw):
         t0 = time.perf_counter()
         res = nc.nc_conv2d(x, w, [x_qp] * 2, w_qp, stride=1, padding="SAME",
@@ -571,7 +608,7 @@ def _fault_rows():
         return res, time.perf_counter() - t0
 
     (out0, cyc0), wall0 = conv()
-    (out1, cyc1, st1), wall1 = conv(integrity=True, return_stats=True)
+    (out1, cyc1, st1), wall1 = conv(plan=checked, return_stats=True)
     if not np.array_equal(np.asarray(out0), np.asarray(out1)):
         raise RuntimeError("fault gate: integrity-checked conv logits "
                            "diverge from unchecked on clean execution")
@@ -603,7 +640,7 @@ def _fault_rows():
                   "compute": dict(compute_rate=1.0)}[cls]
             prof = faults.FaultProfile(seed=5, n_slices=geom.n_slices, **kw)
         with faults.inject(prof) as fs:
-            (outf, _, stf), _ = conv(integrity=True, return_stats=True)
+            (outf, _, stf), _ = conv(plan=checked, return_stats=True)
         if fs.corrupt_attempts == 0:
             raise RuntimeError(f"fault gate: class {cls!r} injected nothing "
                                f"at rate 1 — the sweep is not covering it")

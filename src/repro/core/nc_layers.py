@@ -268,11 +268,7 @@ def nc_conv2d(
     geom: CacheGeometry = XEON_E5_35MB,
     layer_spec: LayerSpec | None = None,
     plan: sched.SlicePlan | None = None,
-    occupancy: sched.LayerOccupancy | str | None = None,
     engine: str | None = None,
-    overlap: bool = False,
-    integrity: bool = False,
-    compressed: bool = False,
     return_stats: bool = False,
 ):
     """Quantized conv through the array model (packed-resident + tiled).
@@ -293,12 +289,19 @@ def nc_conv2d(
     not B*E*F*M*K); the batch folds into the row axis, and the packed
     window rows of a row tile are packed once and broadcast across every
     filter, while the filter word grid packs ONCE per layer per batch
-    (§VI-C residency).  Tile sizes come from ``plan`` (a
-    :class:`~repro.core.schedule.SlicePlan`) when given, else from
-    :func:`~repro.core.schedule.plan_layer` — one plan object from the
-    mapper to the packed engine.  Cycle accounting is unchanged by tiling
-    or batching: each lane group reports the same ``per_dot_cycles`` as
-    the untiled single-image formulation.
+    (§VI-C residency).  Cycle accounting is unchanged by tiling or
+    batching: each lane group reports the same ``per_dot_cycles`` as the
+    untiled single-image formulation.
+
+    ``plan`` (a :class:`~repro.core.schedule.SlicePlan`) is the only
+    carrier of the layer's decisions — its tile sizes, pruned pass list,
+    double buffering, checking, filter store and backend pin — built by
+    :func:`~repro.core.schedule.plan_layer`; this function only reads it.
+    ``plan=None`` plans the dense default ``plan_layer(spec, geom,
+    batch=B)``.  ``tile_pixels`` / ``tile_filters`` re-plan the tile
+    sizes and keep every other decision of the given plan (its
+    occupancy, overlap, integrity, compression, backend pin and
+    quarantined slices).
 
     ``engine`` names a registered backend (``core/backends.py``:
     ``host``, ``jit``, ``pallas``, ...); ``None`` resolves by
@@ -317,14 +320,15 @@ def nc_conv2d(
     list — only live filter columns run through the packed engine, while
     the outputs of all-zero filters are filled from the exact affine
     identity ``zw * sum(x)`` (bit-identical to computing them; the cycle
-    charge follows the executed lanes).  ``occupancy="detect"`` scans the
-    quantized filter rows at pack time (``bitserial.filter_occupancy``)
-    and plans sparse; an explicit :class:`LayerOccupancy` is validated
-    against the actual weights (a filter it marks zero must BE zero —
-    under-claiming sparsity is allowed, over-claiming raises).  Dense
-    plans (no occupancy) behave exactly as before.
+    charge follows the executed lanes).  Build the occupancy from the
+    quantized filter rows with
+    :meth:`~repro.core.schedule.LayerOccupancy.from_filter_rows` and pass
+    it to ``plan_layer``.  The plan's occupancy is validated against the
+    actual weights (a filter it marks zero must BE zero — under-claiming
+    sparsity is allowed, over-claiming raises).  Dense plans (no
+    occupancy) behave exactly as before.
 
-    §IV-E double buffering (``overlap=True``, or a plan that granted it):
+    §IV-E double buffering (a plan that granted ``overlap``):
     the engine runs the plan's explicit (load, compute) stage split —
     while tile k's MAC+reduce is in flight (the bucketed-jit dispatch is
     asynchronous), the host packs tile k+1's filter columns and window
@@ -332,12 +336,10 @@ def nc_conv2d(
     device->host copy is deferred by exactly one tile (depth-1 pipeline,
     matching the single prefetch buffer the reserved I/O way has headroom
     for).  Results are byte-identical to the serial path — the flag only
-    reorders WHEN packing and copies happen.  Like sparsity, overlap is a
-    plan decision: requesting ``overlap=True`` alongside an explicit plan
-    raises (the plan already decided).
+    reorders WHEN packing and copies happen.
 
-    Integrity + fault path (PR 7, ``integrity=True`` or a plan that set
-    it, and/or an active ``faults.inject`` scope): each tile pass runs
+    Integrity + fault path (a plan that set ``integrity``, and/or an
+    active ``faults.inject`` scope): each tile pass runs
     checked — ABFT checksum columns (``bitserial.abft_checksums``) are
     verified against the pass's MAC+reduce output; a mismatch triggers
     bounded re-execution (clean operands are re-packed from the resident
@@ -349,20 +351,16 @@ def nc_conv2d(
     tile per re-execution).  The checked path executes tiles serially and
     stores immediately — outputs stay byte-identical to the unchecked
     path on clean passes, and with integrity off and no fault scope the
-    original unchecked loop runs bit for bit.  Like sparsity and overlap,
-    integrity is a plan decision: ``integrity=True`` alongside an
-    explicit plan raises.
+    original unchecked loop runs bit for bit.
 
-    Compressed filter residency (PR 8, ``compressed=True`` or a plan
-    that set it): the layer's resident filter store is the CSR-per-bit-
+    Compressed filter residency (a plan that set ``compressed``): the
+    layer's resident filter store is the CSR-per-bit-
     plane :class:`~repro.core.bitserial.CompressedPlanes` — live columns
     of live planes only — and each tile's filter slice is reconstructed
     from it before the packed MAC+reduce.  Dead columns/planes come back
     as zero words (the multiply's identity), so outputs are BYTE-
     IDENTICAL to dense execution at every pruning level
-    (tests/test_sparsity.py's differential sweep).  Like sparsity,
-    overlap and integrity, compression is a plan decision:
-    ``compressed=True`` alongside an explicit plan raises.
+    (tests/test_sparsity.py's differential sweep).
 
     Layer-granular dispatch: on the unchecked path, a backend that
     declares ``layer_calls`` (``pallas``) runs one device program for
@@ -427,52 +425,22 @@ def nc_conv2d(
         name="nc_conv2d", kind="conv", H=H, R=R, S=S, C=Cw, M=M, E=E,
         stride=stride)
     zw_int = int(w_qp.zero_point)
-    replan = plan is None or tile_pixels is not None or tile_filters is not None
-    if occupancy is not None and not replan:
-        raise ValueError("pass sparsity through the plan's occupancy, or "
-                         "let nc_conv2d plan (occupancy= with an explicit "
-                         "plan is ambiguous)")
-    if overlap and not replan:
-        raise ValueError("request overlap through the plan "
-                         "(plan_layer(..., overlap=True)); overlap= with "
-                         "an explicit plan is ambiguous")
-    if integrity and not replan:
-        raise ValueError("request integrity through the plan "
-                         "(plan_layer(..., integrity=True)); integrity= "
-                         "with an explicit plan is ambiguous")
-    if compressed and not replan:
-        raise ValueError("request compression through the plan "
-                         "(plan_layer(..., compressed=True)); compressed= "
-                         "with an explicit plan is ambiguous")
     if (engine is not None and plan is not None
             and plan.backend not in (None, engine)):
         raise ValueError("pick the backend through the plan "
                          "(plan_layer(..., backend=...)); engine= "
                          "contradicting a backend-carrying plan is "
                          "ambiguous")
-    if replan:
-        occ = occupancy
-        if isinstance(occ, str):
-            if occ != "detect":
-                raise ValueError(f"occupancy must be a LayerOccupancy, "
-                                 f"'detect' or None, got {occ!r}")
-            occ = sched.LayerOccupancy.from_filter_rows(
-                w_rows, w_qp.bits, zw_int)
-        quarantined: tuple = ()
-        backend_pin: str | None = None
-        if plan is not None:
-            if occ is None:
-                occ = plan.occupancy  # tile overrides must not drop sparsity
-            overlap = overlap or plan.overlap  # ... nor drop double buffering
-            integrity = integrity or plan.integrity  # ... nor drop checking
-            compressed = compressed or plan.compressed  # ... nor decompress
-            backend_pin = plan.backend  # ... nor drop the backend pin
-            quarantined = plan.quarantined_slices
+    if plan is None or tile_pixels is not None or tile_filters is not None:
+        # tile overrides re-plan the tile sizes only: every other decision
+        # of the given plan carries over
+        kept = {} if plan is None else dict(
+            occupancy=plan.occupancy, overlap=plan.overlap,
+            integrity=plan.integrity, compressed=plan.compressed,
+            backend=plan.backend,
+            quarantined_slices=plan.quarantined_slices)
         plan = sched.plan_layer(spec, geom, batch=B, tile_pixels=tile_pixels,
-                                tile_filters=tile_filters, occupancy=occ,
-                                overlap=overlap, integrity=integrity,
-                                quarantined_slices=quarantined,
-                                compressed=compressed, backend=backend_pin)
+                                tile_filters=tile_filters, **kept)
     # backend selection is pure configuration: explicit engine= > the
     # plan's pin > NC_BACKEND > pallas on a TPU > host (contradictions
     # raised above)
@@ -1006,8 +974,8 @@ def nc_fc(x: jax.Array, w: jax.Array,
     """FC as a 1x1 conv over a 1x1 'image' (§IV-D).
 
     ``x``: [K] or batched [B, K] (each row one image's feature vector —
-    the batch folds into the conv's row axis); tiling kwargs pass through
-    to :func:`nc_conv2d`."""
+    the batch folds into the conv's row axis); ``plan``, ``engine`` and the
+    tiling keywords pass through to :func:`nc_conv2d`."""
     xa = np.asarray(x)
     w4 = np.asarray(w)[None, None, :, :]
     if xa.ndim == 2:  # batched: [B, K] -> [B, 1, 1, K] image batch

@@ -720,10 +720,6 @@ def nc_forward(params: dict, x: jax.Array, config,
                engine: str | None = None,
                schedule: sched.NetworkSchedule | None = None,
                wpack: dict | None = None,
-               sparse: bool = False,
-               overlap: bool = False,
-               integrity: bool = False,
-               compressed: bool = False,
                stream_chunk: int | None = None):
     """Quantized forward pass of the network ``config`` describes, through
     the bit-serial emulation.
@@ -750,56 +746,34 @@ def nc_forward(params: dict, x: jax.Array, config,
     engine.
     An explicit engine that contradicts a backend-carrying schedule
     raises (the schedule already decided).
-    ``schedule`` accepts a precomputed :class:`NetworkSchedule` (the
-    serving path plans once per batch size); by default one is planned
-    here, and the SAME object prices the run via
-    ``simulator.simulate_network(schedule)``.  ``wpack`` accepts the
-    output of :func:`prepare_conv_weights` so resident filters quantize
-    once per deployment instead of once per call.
-
-    ``sparse=True`` plans against the weights' detected value sparsity
-    (:func:`network_occupancy`): zero-filter passes are dropped from the
-    executed pass list and credited in the modeled cycles, with outputs
-    BYTE-IDENTICAL to the dense run on the same weights (the pruned
-    filters' outputs are exact affine constants).  A ``schedule`` built
-    with occupancy implies the same; ``sparse`` only controls the plan
-    made here.
-
-    ``overlap=True`` plans §IV-E double buffering: every layer the
-    legality rule grants streams pass k+1's filter columns while pass k's
-    MAC+reduce runs (core/nc_layers.py's depth-1 pipeline), with logits
-    byte-identical to the serial run.  Like ``sparse``, it only controls
-    the plan made here — a precomputed ``schedule`` already decided, and
-    combining the two raises.
-
-    ``integrity=True`` plans ABFT checksum verification: every
-    executed pass is verified against exact column/row checksums, detected
-    corruption triggers bounded re-execution (and stuck-slice quarantine +
-    re-plan under an active ``core.faults`` scope), and the modeled cycles
-    pay the additive ``checksum_pass_cycles`` term.  Logits stay
-    byte-identical to the unchecked run — verification never perturbs the
-    data path.  Like the other plan flags it raises when combined with an
-    explicit ``schedule`` (build that with ``plan_network(...,
-    integrity=True)`` instead).
-
-    ``compressed=True`` plans CSR bit-plane filter residency:
-    every conv/fc layer's resident footprint shrinks to the live bit
-    planes plus a per-plane live-column bitmap
-    (``mapper.compressed_filter_bytes``), the engine stores and streams
-    filters through :class:`~repro.core.bitserial.CompressedPlanes`, and
-    the modeled time earns the exact residency credit (dense minus
-    compressed at filter bandwidth).  Logits stay BYTE-IDENTICAL to the
-    dense store — decompression scatters live columns into zero words,
-    the multiply identity.  Like the other plan flags it raises when
-    combined with an explicit ``schedule``.
+    ``schedule`` (a :class:`NetworkSchedule`) is the only carrier of the
+    layers' decisions — pruned pass lists, double buffering, checking,
+    filter store and backend pin — built by
+    :func:`~repro.core.schedule.plan_network` (the serving path plans once
+    per batch size); the forward only reads it, and the SAME object prices
+    the run via ``simulator.simulate_network(schedule)``.  By default the
+    dense ``plan_network(specs, geom, batch=B)`` is planned here.  A
+    schedule built with ``occupancy=network_occupancy(wpack, config)``
+    drops zero-filter passes from the executed pass list and credits them
+    in the modeled cycles; ``overlap=True`` double-buffers every layer the
+    legality rule grants (§IV-E); ``integrity=True`` verifies every pass
+    against ABFT checksums, with bounded re-execution and, under an active
+    ``core.faults`` scope, stuck-slice quarantine; ``compressed=True``
+    keeps every filter store CSR per bit plane.  Each of them leaves the
+    logits BYTE-IDENTICAL to the dense serial run on the same weights.
+    ``wpack`` accepts the output of :func:`prepare_conv_weights` so
+    resident filters quantize once per deployment instead of once per
+    call.
 
     ``stream_chunk=N`` additionally streams the batch through the network
     in chunks of ``N`` images advanced in a skewed wavefront — layer L of
     chunk i computes while chunk i+1 runs layer L-1 (cross-layer §VI-C
-    streaming).  Logits stay byte-identical (quantization is per-image),
-    but each chunk packs its own filter grids (``filter_loads`` in the
-    report sums to the chunk count) and plans its own chunk-sized
-    schedule, so it is an experiment flag, not the serving default.
+    streaming).  Each chunk runs a chunk-sized schedule planned with the
+    decisions of ``schedule`` (its overlap, integrity, compression,
+    backend pin and per-layer occupancy).  Logits stay byte-identical
+    (quantization is per-image), but each chunk packs its own filter
+    grids (``filter_loads`` in the report sums to the chunk count), so it
+    is an experiment flag, not the serving default.
 
     Returns ``(logits [B?, classes], NCForwardReport)`` — the report pairs
     each layer's emulated arithmetic cycles (min/max tree included) with
@@ -833,28 +807,8 @@ def nc_forward(params: dict, x: jax.Array, config,
     specs = {s.name: s for s in specs_list}
     if wpack is None:
         wpack = prepare_conv_weights(params, config)
-    if schedule is not None and overlap:
-        raise ValueError("request overlap through the schedule "
-                         "(plan_network(..., overlap=True)); overlap= with "
-                         "an explicit schedule is ambiguous")
-    if schedule is not None and integrity:
-        raise ValueError("request integrity through the schedule "
-                         "(plan_network(..., integrity=True)); integrity= "
-                         "with an explicit schedule is ambiguous")
-    if schedule is not None and compressed:
-        raise ValueError("request compression through the schedule "
-                         "(plan_network(..., compressed=True)); compressed= "
-                         "with an explicit schedule is ambiguous")
-    if schedule is not None and stream_chunk is not None:
-        raise ValueError("stream_chunk replans per chunk; it cannot honor "
-                         "an explicit whole-batch schedule")
-    occ = (network_occupancy(wpack, config)
-           if sparse and schedule is None else None)
-
-    def plan(n):
-        return sched.plan_network(specs_list, geom, batch=n, occupancy=occ,
-                                  overlap=overlap, integrity=integrity,
-                                  compressed=compressed)
+    if schedule is None:
+        schedule = sched.plan_network(specs_list, geom, batch=B)
 
     def executor(sc):
         return _Exec(wpack, specs, {p.spec.name: p for p in sc.layers}, geom,
@@ -864,7 +818,13 @@ def nc_forward(params: dict, x: jax.Array, config,
         # cross-layer streaming: chunk generators advanced in a skewed
         # wavefront — chunk i runs stage t while chunk i+1 runs stage t-1
         chunks = [x4[i:i + stream_chunk] for i in range(0, B, stream_chunk)]
-        runs = [(executor(plan(xc.shape[0])), {}) for xc in chunks]
+        occ = {p.spec.name: p.occupancy for p in schedule.layers
+               if p.occupancy is not None}
+        runs = [(executor(sched.plan_network(
+            specs_list, geom, batch=xc.shape[0], occupancy=occ,
+            overlap=schedule.overlap, integrity=schedule.integrity,
+            compressed=schedule.compressed, backend=schedule.backend)), {})
+            for xc in chunks]
         waiting = [_nc_stage_gen(xc, config, ex, out)
                    for xc, (ex, out) in zip(chunks, runs)]
         active: list = []
@@ -885,7 +845,7 @@ def nc_forward(params: dict, x: jax.Array, config,
                                       for ex, _ in runs))
         return jnp.asarray(logits if batched else logits[0]), report
 
-    ex, out = executor(schedule if schedule is not None else plan(B)), {}
+    ex, out = executor(schedule), {}
     for _ in _nc_stage_gen(x4, config, ex, out):
         pass
     report = NCForwardReport(config.name, tuple(ex.records), batch=B,

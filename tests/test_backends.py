@@ -49,8 +49,10 @@ from repro.core import quantize as q
 from repro.core import schedule as sched
 from repro.core.cache_geometry import XEON_E5_35MB
 from repro.core.mapper import LayerSpec
+from nc_plans import conv_plan, fc_plan, occupancy
 
 GEOM = XEON_E5_35MB
+GEOM_1SLICE = XEON_E5_35MB.scaled(1)
 
 # host and jit conformance is tier-1; the interpret-mode sweep runs under
 # the `backends` marker (satellite: pytest.ini addopts excludes it)
@@ -102,11 +104,13 @@ def _run_conv(case, engine):
     kw = dict(case)
     xq, wq, qps, w_qp = _quantized_conv_case(
         0xC0FFEE, bits=kw.pop("bits"), prune=kw.pop("prune", 0.0),
-        batch=kw.setdefault("batch", 1))
-    kw.pop("batch")
+        batch=kw.pop("batch", 1))
     stride = kw.pop("stride", 1)
-    out, cycles = nc.nc_conv2d(xq, wq, qps, w_qp, stride, geom=GEOM,
-                               occupancy="detect", engine=engine, **kw)
+    padding = kw.pop("padding", "VALID")
+    plan = conv_plan(xq, wq, stride, padding=padding, geom=GEOM,
+                     occupancy=occupancy(wq, w_qp), **kw)
+    out, cycles = nc.nc_conv2d(xq, wq, qps, w_qp, stride, padding=padding,
+                               geom=GEOM, plan=plan, engine=engine)
     return np.asarray(out), cycles
 
 
@@ -139,10 +143,11 @@ def test_fc_conformance(backend, batch):
     x_qp = q.QuantParams(scale=np.float32(1 / 256), zero_point=0)
     w_qp = q.QuantParams(scale=np.float32(0.02), zero_point=11)
     qps = [x_qp] * batch if batch > 1 else x_qp
-    ref, ref_cycles = nc.nc_fc(x.astype(np.uint8), w, qps, w_qp,
-                               occupancy="detect", engine="host")
-    out, cycles = nc.nc_fc(x.astype(np.uint8), w, qps, w_qp,
-                           occupancy="detect", engine=backend)
+    plan = fc_plan(x, w, occupancy=occupancy(w, w_qp))
+    ref, ref_cycles = nc.nc_fc(x.astype(np.uint8), w, qps, w_qp, plan=plan,
+                               engine="host")
+    out, cycles = nc.nc_fc(x.astype(np.uint8), w, qps, w_qp, plan=plan,
+                           engine=backend)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     assert cycles == ref_cycles
 
@@ -212,16 +217,16 @@ def test_dispatch_stats_count_the_bytes_of_a_pallas_call():
 # Layer-granular dispatch: a ``layer_calls`` backend takes a layer's whole
 # pass list in one call on the unchecked path
 # ---------------------------------------------------------------------------
-# (conv case, nc_conv2d options): tiles are small so every layer has
-# several row tiles and filter tiles
+# (conv case, plan decisions; ``sparse`` plans the detected occupancy):
+# tiles are small so every layer has several row tiles and filter tiles
 LAYER_CASES = [
     pytest.param(dict(C=4), dict(tile_pixels=7, tile_filters=2),
                  id="dense-K36"),
     pytest.param(dict(C=4, prune=0.5),
-                 dict(tile_pixels=7, tile_filters=2, occupancy="detect"),
+                 dict(tile_pixels=7, tile_filters=2, sparse=True),
                  id="occupancy-zero-filters"),
     pytest.param(dict(C=4, prune=0.5),
-                 dict(tile_pixels=7, tile_filters=2, occupancy="detect",
+                 dict(tile_pixels=7, tile_filters=2, sparse=True,
                       compressed=True, overlap=True),
                  id="compressed-overlap"),
     pytest.param(dict(C=4, batch=2),
@@ -233,13 +238,17 @@ LAYER_CASES = [
 ]
 
 
-def _layer_run(conv, opts, engine):
-    kw = dict(conv)
-    xq, wq, qps, w_qp = _quantized_conv_case(0xBEEF, **kw)
+def _layer_run(conv, opts, engine, geom=GEOM):
+    opts = dict(opts)
+    xq, wq, qps, w_qp = _quantized_conv_case(0xBEEF, **conv)
+    padding = opts.pop("padding", "VALID")
+    if opts.pop("sparse", False):
+        opts["occupancy"] = occupancy(wq, w_qp)
+    plan = conv_plan(xq, wq, padding=padding, geom=geom, **opts)
     backends.dispatch_stats_clear()
-    out, cycles, stats = nc.nc_conv2d(xq, wq, qps, w_qp, geom=GEOM,
-                                      engine=engine, return_stats=True,
-                                      **opts)
+    out, cycles, stats = nc.nc_conv2d(xq, wq, qps, w_qp, padding=padding,
+                                      geom=geom, plan=plan, engine=engine,
+                                      return_stats=True)
     return np.asarray(out), cycles, stats, backends.dispatch_stats()[engine]
 
 
@@ -318,6 +327,43 @@ def test_per_tile_paths_dispatch_once_per_pass(engine, integrity):
     assert stats.integrity == integrity
     assert stats.tiles > 1
     assert st["native"] == st["passes"] == stats.tiles
+
+
+@pytest.mark.parametrize("compressed", [False, True])
+@pytest.mark.parametrize("overlap", [False, True])
+@pytest.mark.parametrize("engine", ["host", "pallas"])
+def test_each_tile_packs_once_per_call(engine, overlap, compressed,
+                                       monkeypatch):
+    """The load stage packs each row tile and each filter tile exactly
+    once per ``nc_conv2d`` call, on the per-tile loop (``host``) and the
+    layer-call loop (``pallas``), with and without double buffering and
+    the compressed store.  The layer is a ResNet-style 3x3 SAME conv cut
+    to 10 px and 32 -> 64 channels, with half its filters pruned: 4 row
+    tiles and 2 filter tiles of the live set, in 2 serialized passes on
+    one slice, so double buffering is granted."""
+    conv = dict(C=32, M=64, img=10, prune=0.5)
+    xq, wq, qps, w_qp = _quantized_conv_case(0xBEEF, **conv)
+    ref, *_ = nc.nc_conv2d(xq, wq, qps, w_qp, padding="SAME", engine="host")
+    packed = {"x": [], "w": []}
+    for side, name in (("x", "_pack_x_rows"), ("w", "_pack_w_rows")):
+        def spy(rows, n_bits, _pack=getattr(nc, name), _side=side):
+            packed[_side].append(np.array(rows))
+            return _pack(rows, n_bits)
+        monkeypatch.setattr(nc, name, spy)
+    out, _, stats, _ = _layer_run(
+        conv, dict(padding="SAME", tile_pixels=32, tile_filters=16,
+                   sparse=True, overlap=overlap, compressed=compressed),
+        engine, geom=GEOM_1SLICE)
+    assert stats.overlap == overlap and stats.compressed == compressed
+    assert stats.tiles == 4 * 2
+    np.testing.assert_array_equal(out, np.asarray(ref))
+    # every window row and every live filter row goes through a pack once
+    assert sum(len(a) for a in packed["x"]) == 10 * 10
+    assert len(packed["x"]) == 4
+    w_rows = wq.reshape(-1, 64).T
+    live = w_rows[~(w_rows == w_qp.zero_point).all(axis=1)]
+    np.testing.assert_array_equal(np.concatenate(packed["w"]), live)
+    assert len(packed["w"]) == (2 if overlap else 1)
 
 
 # ---------------------------------------------------------------------------

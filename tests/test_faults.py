@@ -46,6 +46,7 @@ from repro.core.simulator import (SimConstants, batch_time_s,
                                   modeled_layer_cycles, simulate_layer,
                                   simulate_network)
 from repro.models import inception
+from nc_plans import conv_plan
 
 GEOM = XEON_E5_35MB
 GEOM_1SLICE = XEON_E5_35MB.scaled(1)
@@ -63,10 +64,16 @@ def _conv_case(seed=0, B=2, img=8, C=3, M=16):
     return x, w, x_qp, w_qp
 
 
-def _conv(case, padding="SAME", geom=GEOM, **kw):
+def _conv(case, padding="SAME", geom=GEOM, engine=None, return_stats=False,
+          **decisions):
+    """The conv of ``case``, under a plan with ``decisions`` (``integrity``,
+    ``overlap``) when any are given, else under the default plan."""
     x, w, x_qp, w_qp = case
+    plan = (conv_plan(x, w, padding=padding, geom=geom, **decisions)
+            if decisions else None)
     return nc.nc_conv2d(x, w, [x_qp] * x.shape[0], w_qp, stride=1,
-                        padding=padding, geom=geom, **kw)
+                        padding=padding, geom=geom, plan=plan, engine=engine,
+                        return_stats=return_stats)
 
 
 def _profile_for(cls, rate=1.0, seed=5, geom=GEOM, layer="nc_conv2d"):
@@ -596,17 +603,13 @@ def test_nc_forward_integrity_clean_and_faulted_bit_identical():
     params = inception.init_params(jax.random.PRNGKey(0), config=cfg)
     rng = np.random.default_rng(3)
     x = rng.random((cfg.img, cfg.img, 3)).astype(np.float32)
+    net = sched.plan_network(inception.inception_v3_specs(cfg), GEOM,
+                             batch=1, integrity=True)
     ref, rep0 = inception.nc_forward(params, x, config=cfg)
-    out, rep1 = inception.nc_forward(params, x, config=cfg, integrity=True)
+    out, rep1 = inception.nc_forward(params, x, config=cfg, schedule=net)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
     assert all(r.integrity for r in rep1.layers
                if r.kind in ("conv", "fc"))
-    # integrity=True on an explicit schedule is ambiguous — rejected
-    net = sched.plan_network(inception.inception_v3_specs(cfg), GEOM,
-                             batch=1, integrity=True)
-    with pytest.raises(ValueError, match="schedule"):
-        inception.nc_forward(params, x, config=cfg, schedule=net,
-                             integrity=True)
     # the integrity-planned schedule routes the checked path end to end
     # under faults, recovering bit-identically with consistent counters
     prof = faults.FaultProfile(seed=2, filter_flip_rate=0.5,

@@ -6,6 +6,9 @@ import pytest
 
 from repro.core import nc_layers as nc
 from repro.core import quantize as q
+from repro.core import schedule as sched
+from repro.core.cache_geometry import XEON_E5_35MB
+from repro.core.mapper import LayerSpec
 
 jax.config.update("jax_enable_x64", True)
 
@@ -84,3 +87,29 @@ def test_nc_fc():
     out, _ = nc.nc_fc(jnp.asarray(x), jnp.asarray(w), x_qp, w_qp)
     got = np.asarray(out, np.float64) * float(x_qp.scale) * float(w_qp.scale)
     np.testing.assert_allclose(got, x @ w, atol=0.2)
+
+
+def test_nc_conv2d_default_plan_is_plan_layer():
+    """``plan=None`` runs the dense default plan, ``plan_layer(spec, geom,
+    batch=B)`` for the spec the conv derives from its operands: the same
+    values, cycles and ``ConvStats`` (the plan included) as that plan
+    given explicitly, on a batch of 2 with SAME padding and stride 2."""
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(2, 9, 9, 3)).astype(np.float32)
+    w = rng.normal(size=(3, 3, 3, 6)).astype(np.float32)
+    x_qp = q.choose_qparams(jnp.float32(x.min()), jnp.float32(x.max()))
+    w_qp = q.choose_qparams(jnp.float32(w.min()), jnp.float32(w.max()))
+    geom = XEON_E5_35MB.scaled(1)
+    # SAME at stride 2 pads 9 px by (1, 1): 11 px in, 5 px out
+    spec = LayerSpec(name="nc_conv2d", kind="conv", H=11, R=3, S=3, C=3,
+                     M=6, E=5, stride=2)
+    args = (x, w, [x_qp] * 2, w_qp, 2)
+    out0, cyc0, st0 = nc.nc_conv2d(*args, padding="SAME", geom=geom,
+                                   return_stats=True)
+    out1, cyc1, st1 = nc.nc_conv2d(
+        *args, padding="SAME", geom=geom, return_stats=True,
+        plan=sched.plan_layer(spec, geom, batch=2))
+    np.testing.assert_array_equal(np.asarray(out0), np.asarray(out1))
+    assert np.asarray(out0).dtype == np.asarray(out1).dtype
+    assert cyc0 == cyc1
+    assert st0 == st1

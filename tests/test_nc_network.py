@@ -13,14 +13,17 @@ import jax
 import numpy as np
 import pytest
 
+from repro.core import schedule as sched
 from repro.models import inception
 
 GOLDEN = json.loads((pathlib.Path(__file__).parent / "golden"
                      / "inception_reduced_forward.json").read_text())
+# a case's ``prune`` runs pruned weights under a schedule planned with
+# their occupancy and the ``plan`` decisions
 CASES = {
     "dense_b2": dict(),
-    "sparse_overlap_compressed_b2": dict(sparse=True, overlap=True,
-                                         compressed=True, prune=True),
+    "sparse_overlap_compressed_b2": dict(
+        prune=True, plan=dict(overlap=True, compressed=True)),
     "stream_chunk1_b2": dict(stream_chunk=1),
 }
 
@@ -56,8 +59,12 @@ def test_inception_forward_matches_the_golden(x32, case):
                                         dtype=np.float32)
     kw = dict(CASES[case])
     if kw.pop("prune", False):
-        kw["wpack"] = inception.prune_wpack(
+        wpack = kw["wpack"] = inception.prune_wpack(
             inception.prepare_conv_weights(params, cfg))
+        kw["schedule"] = sched.plan_network(
+            inception.specs(cfg), batch=2,
+            occupancy=inception.network_occupancy(wpack, cfg),
+            **kw.pop("plan"))
     logits, rep = inception.nc_forward(params, x, config=cfg, engine="host",
                                        **kw)
     gold = GOLDEN[case]

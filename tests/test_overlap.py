@@ -9,9 +9,9 @@
   and ``nc_forward`` (including ``stream_chunk`` cross-layer streaming
   and the sparse x overlap composition) return BYTE-IDENTICAL outputs to
   the serial plans on the same weights.
-* **Legality + API guards** — single-pass layers, pools, and fully
-  pruned layers are denied overlap; ``overlap=`` alongside an explicit
-  plan/schedule raises (overlap is a plan decision, like sparsity).
+* **Legality** — single-pass layers, pools, and fully pruned layers are
+  denied overlap.  Overlap is a plan decision, like sparsity: the
+  executors take no ``overlap`` keyword, only the plan or schedule.
 
 The measured wall-time side (serial vs overlapped batch-4 pair) lives in
 ``benchmarks/kernel_bench.py`` + ``benchmarks/sched_breakdown.py``,
@@ -170,7 +170,7 @@ def test_overlap_legality_denials(reduced_specs):
 
 
 # ---------------------------------------------------------------------------
-# Engine differential: byte identity + API guards
+# Engine differential: byte identity
 # ---------------------------------------------------------------------------
 def test_nc_conv2d_overlap_byte_identical():
     """The prefetch + deferred-store execution path returns the same
@@ -193,30 +193,56 @@ def test_nc_conv2d_overlap_byte_identical():
     assert stats.filter_loads == 1
 
 
-def test_overlap_with_explicit_plan_raises():
-    rng = np.random.default_rng(3)
-    wq = rng.integers(0, 256, size=(3, 3, 3, 8)).astype(np.uint8)
-    w_qp = q.QuantParams(scale=np.float32(0.1), zero_point=0)
-    x = rng.normal(size=(8, 8, 3)).astype(np.float32)
-    x_qp = q.choose_qparams(jnp.float32(x.min()), jnp.float32(x.max()))
-    spec = LayerSpec(name="t", kind="conv", H=8, R=3, S=3, C=3, M=8, E=6)
-    plan = sched.plan_layer(spec, GEOM)
-    with pytest.raises(ValueError, match="plan_layer"):
-        nc.nc_conv2d(x, wq, x_qp, w_qp, plan=plan, overlap=True)
-
-
-def test_nc_forward_overlap_schedule_guards():
+def test_stream_chunk_runs_the_schedules_decisions(monkeypatch):
+    """``stream_chunk`` plans each chunk with the given schedule's own
+    decisions.  A batch of 2 streamed one image per chunk under a pruned,
+    overlapped, compressed schedule returns the whole-batch run's logits
+    byte for byte, and every layer's cycles: the modeled ones, and the
+    emulated ones but for the §IV-D min/max tree, which runs once per
+    chunk; each chunk's schedule carries the occupancy, overlap and
+    compression of the given one."""
     cfg = inception.reduced_config(img=39, width_div=8, classes=8,
                                    stages=("a",))
     params = inception.init_params(jax.random.PRNGKey(0), config=cfg)
-    x = np.zeros((39, 39, 3), np.float32)
-    schedule = sched.plan_network(inception.inception_v3_specs(cfg), GEOM)
-    with pytest.raises(ValueError, match="plan_network"):
-        inception.nc_forward(params, x, config=cfg, schedule=schedule,
-                             overlap=True)
-    with pytest.raises(ValueError, match="stream_chunk"):
-        inception.nc_forward(params, x, config=cfg, schedule=schedule,
-                             stream_chunk=1)
+    # an eighth of the filters pruned: the stem keeps 2 passes at 1 slice,
+    # so double buffering is granted there
+    wpack = inception.prune_wpack(
+        inception.prepare_conv_weights(params, cfg), 0.125)
+    xb = np.asarray(jax.random.uniform(
+        jax.random.PRNGKey(2), (2, 39, 39, 3), jnp.float32))
+    schedule = sched.plan_network(
+        inception.inception_v3_specs(cfg), GEOM_1SLICE, batch=2,
+        occupancy=inception.network_occupancy(wpack, cfg), overlap=True,
+        compressed=True)
+    run = dict(config=cfg, geom=GEOM_1SLICE, wpack=wpack, schedule=schedule)
+    whole, rw = inception.nc_forward(params, xb, **run)
+    chunk_schedules = []
+    plan_network = sched.plan_network
+
+    def spy(*a, **kw):
+        chunk_schedules.append(plan_network(*a, **kw))
+        return chunk_schedules[-1]
+
+    monkeypatch.setattr(sched, "plan_network", spy)
+    chunked, rc = inception.nc_forward(params, xb, stream_chunk=1, **run)
+    assert np.asarray(chunked).tobytes() == np.asarray(whole).tobytes()
+    assert rc.concat_requant_cycles == rw.concat_requant_cycles
+    for lc, lw in zip(rc.layers, rw.layers, strict=True):
+        assert (lc.name, lc.emulated_cycles - lc.minmax_cycles,
+                lc.modeled_cycles, lc.serial_passes, lc.skipped_passes,
+                lc.zero_filters, lc.overlap) == \
+            (lw.name, lw.emulated_cycles - lw.minmax_cycles,
+             lw.modeled_cycles, lw.serial_passes, lw.skipped_passes,
+             lw.zero_filters, lw.overlap)
+    assert sum(l.zero_filters for l in rc.layers) > 0
+    assert sum(1 for l in rc.layers if l.overlap) > 0
+    assert [sc.batch for sc in chunk_schedules] == [1, 1]
+    for sc in chunk_schedules:
+        assert (sc.overlap, sc.integrity, sc.compressed, sc.backend) == \
+            (True, False, True, None)
+        for pc, pw in zip(sc.layers, schedule.layers, strict=True):
+            assert (pc.occupancy, pc.overlap, pc.compressed) == \
+                (pw.occupancy, pw.overlap, pw.compressed)
 
 
 def test_nc_forward_overlap_and_stream_chunk_byte_identical():
@@ -228,23 +254,29 @@ def test_nc_forward_overlap_and_stream_chunk_byte_identical():
     params = inception.init_params(jax.random.PRNGKey(0), config=cfg)
     xb = np.asarray(jax.random.uniform(
         jax.random.PRNGKey(1), (2, 39, 39, 3), jnp.float32))
+    specs = inception.inception_v3_specs(cfg)
+    over = sched.plan_network(specs, GEOM_1SLICE, batch=2, overlap=True)
     ref, rd = inception.nc_forward(params, xb, config=cfg, geom=GEOM_1SLICE)
     out, ro = inception.nc_forward(params, xb, config=cfg, geom=GEOM_1SLICE,
-                                   overlap=True)
+                                   schedule=over)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     assert sum(1 for l in ro.layers if l.overlap) > 0
     assert ro.total_emulated_cycles == rd.total_emulated_cycles
     chunked, _ = inception.nc_forward(params, xb, config=cfg,
-                                      geom=GEOM_1SLICE, overlap=True,
+                                      geom=GEOM_1SLICE, schedule=over,
                                       stream_chunk=1)
     np.testing.assert_array_equal(np.asarray(chunked), np.asarray(ref))
     # sparse x overlap composition: still byte-identical to sparse-serial
     wpack = inception.prune_wpack(
         inception.prepare_conv_weights(params, cfg), 0.5)
-    sref, _ = inception.nc_forward(params, xb, config=cfg, geom=GEOM_1SLICE,
-                                   wpack=wpack, sparse=True)
-    sout, rso = inception.nc_forward(params, xb, config=cfg,
-                                     geom=GEOM_1SLICE, wpack=wpack,
-                                     sparse=True, overlap=True)
+    occ = inception.network_occupancy(wpack, cfg)
+    sref, _ = inception.nc_forward(
+        params, xb, config=cfg, geom=GEOM_1SLICE, wpack=wpack,
+        schedule=sched.plan_network(specs, GEOM_1SLICE, batch=2,
+                                    occupancy=occ))
+    sout, rso = inception.nc_forward(
+        params, xb, config=cfg, geom=GEOM_1SLICE, wpack=wpack,
+        schedule=sched.plan_network(specs, GEOM_1SLICE, batch=2,
+                                    occupancy=occ, overlap=True))
     np.testing.assert_array_equal(np.asarray(sout), np.asarray(sref))
     assert sum(l.zero_filters for l in rso.layers) > 0

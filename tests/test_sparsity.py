@@ -38,6 +38,7 @@ from repro.core.cache_geometry import XEON_E5_35MB
 from repro.core.mapper import LayerSpec, serial_passes_for
 from repro.core.simulator import modeled_layer_cycles, simulate_network
 from repro.models import inception
+from nc_plans import conv_plan, fc_plan, occupancy
 
 GEOM = XEON_E5_35MB
 GEOM_1SLICE = XEON_E5_35MB.scaled(1)
@@ -79,8 +80,11 @@ def test_sparse_conv_bit_exact_vs_dense(frac, stride, padding, batch,
     dense, cyc_d = nc.nc_conv2d(x, wq, qps, w_qp, stride, padding=padding,
                                 tile_pixels=tile_pixels)
     sparse, cyc_s, stats = nc.nc_conv2d(
-        x, wq, qps, w_qp, stride, padding=padding, tile_pixels=tile_pixels,
-        occupancy="detect", return_stats=True)
+        x, wq, qps, w_qp, stride, padding=padding,
+        plan=conv_plan(x, wq, stride, padding=padding,
+                       tile_pixels=tile_pixels,
+                       occupancy=occupancy(wq, w_qp)),
+        return_stats=True)
     np.testing.assert_array_equal(np.asarray(sparse), np.asarray(dense))
     assert stats.zero_filters == len(idx)
     # the engine charges §III cycles only for the executed (live) lanes
@@ -111,8 +115,8 @@ def test_sparse_fc_bit_exact_vs_dense(frac, k, tile_filters, batch, seed):
     x_qp = q.choose_qparams(jnp.float32(x.min()), jnp.float32(x.max()))
     qps = [x_qp] * batch if batch > 1 else x_qp
     dense, cyc_d = nc.nc_fc(x, wq, qps, w_qp, tile_filters=tile_filters)
-    sparse, cyc_s = nc.nc_fc(x, wq, qps, w_qp, tile_filters=tile_filters,
-                             occupancy="detect")
+    sparse, cyc_s = nc.nc_fc(x, wq, qps, w_qp, plan=fc_plan(
+        x, wq, tile_filters=tile_filters, occupancy=occupancy(wq, w_qp)))
     np.testing.assert_array_equal(np.asarray(sparse), np.asarray(dense))
     assert cyc_s <= cyc_d
 
@@ -190,11 +194,11 @@ def test_overclaiming_occupancy_raises_underclaiming_allowed():
     x_qp = q.choose_qparams(jnp.float32(x.min()), jnp.float32(x.max()))
     live = [m for m in range(8) if m not in idx]
     with pytest.raises(ValueError, match="stale plan"):
-        nc.nc_conv2d(x, wq, x_qp, w_qp,
-                     occupancy=sched.LayerOccupancy(8, (live[0],)))
+        nc.nc_conv2d(x, wq, x_qp, w_qp, plan=conv_plan(
+            x, wq, occupancy=sched.LayerOccupancy(8, (live[0],))))
     dense, _ = nc.nc_conv2d(x, wq, x_qp, w_qp)
-    under, _ = nc.nc_conv2d(x, wq, x_qp, w_qp,
-                            occupancy=sched.LayerOccupancy(8, (int(idx[0]),)))
+    under, _ = nc.nc_conv2d(x, wq, x_qp, w_qp, plan=conv_plan(
+        x, wq, occupancy=sched.LayerOccupancy(8, (int(idx[0]),))))
     np.testing.assert_array_equal(np.asarray(under), np.asarray(dense))
 
 
@@ -386,6 +390,9 @@ def test_nc_forward_sparse_acceptance_batch4():
         inception.prepare_conv_weights(params, cfg), 0.5)
     xb = np.asarray(jax.random.uniform(
         jax.random.PRNGKey(1), (4, cfg.img, cfg.img, 3), jnp.float32))
+    specs = inception.inception_v3_specs(cfg)
+    occ = inception.network_occupancy(wpack, cfg)
+    sparse_schedule = sched.plan_network(specs, GEOM, batch=4, occupancy=occ)
 
     wall_d = wall_s = float("inf")
     for _ in range(2):  # first pass warms the bucketed-jit caches
@@ -394,15 +401,13 @@ def test_nc_forward_sparse_acceptance_batch4():
         wall_d = min(wall_d, time.perf_counter() - t0)
         t0 = time.perf_counter()
         ls, rs = inception.nc_forward(params, xb, config=cfg, wpack=wpack,
-                                      sparse=True)
+                                      schedule=sparse_schedule)
         wall_s = min(wall_s, time.perf_counter() - t0)
 
     np.testing.assert_array_equal(np.asarray(ls), np.asarray(ld))
     assert rs.total_emulated_cycles < rd.total_emulated_cycles
     # modeled credit: exact per layer (pass counts are 1 at paper scale for
     # this miniature, so assert on the 1-slice geometry where they bite)
-    specs = inception.inception_v3_specs(cfg)
-    occ = inception.network_occupancy(wpack, cfg)
     dense1 = sched.plan_network(specs, GEOM_1SLICE, batch=4)
     sparse1 = sched.plan_network(specs, GEOM_1SLICE, batch=4, occupancy=occ)
     assert sparse1.skipped_passes > 0
@@ -505,8 +510,11 @@ def test_compressed_conv_bit_exact_vs_dense(frac, stride, padding, batch,
     dense, cyc_d = nc.nc_conv2d(x, wq, qps, w_qp, stride, padding=padding,
                                 tile_pixels=tile_pixels)
     comp, cyc_c, stats = nc.nc_conv2d(
-        x, wq, qps, w_qp, stride, padding=padding, tile_pixels=tile_pixels,
-        occupancy="detect", compressed=True, return_stats=True)
+        x, wq, qps, w_qp, stride, padding=padding,
+        plan=conv_plan(x, wq, stride, padding=padding,
+                       tile_pixels=tile_pixels,
+                       occupancy=occupancy(wq, w_qp), compressed=True),
+        return_stats=True)
     np.testing.assert_array_equal(np.asarray(comp), np.asarray(dense))
     assert stats.compressed
     if frac < 1.0:
@@ -522,22 +530,11 @@ def test_compressed_fc_bit_exact_vs_dense():
     x = rng.normal(size=(4, k)).astype(np.float32)
     x_qp = q.choose_qparams(jnp.float32(x.min()), jnp.float32(x.max()))
     dense, _ = nc.nc_fc(x, wq, [x_qp] * 4, w_qp)
-    comp, _, stats = nc.nc_fc(x, wq, [x_qp] * 4, w_qp, occupancy="detect",
-                              compressed=True, return_stats=True)
+    comp, _, stats = nc.nc_fc(
+        x, wq, [x_qp] * 4, w_qp, return_stats=True,
+        plan=fc_plan(x, wq, occupancy=occupancy(wq, w_qp), compressed=True))
     np.testing.assert_array_equal(np.asarray(comp), np.asarray(dense))
     assert stats.compressed
-
-
-def test_compressed_with_explicit_plan_raises():
-    spec = _spec(M=8, E=4, C=4)
-    plan = sched.plan_layer(spec, GEOM)
-    rng = np.random.default_rng(0)
-    wq, w_qp, _ = _pruned_case(rng, M=8, C=4, frac=0.0)
-    x = rng.normal(size=(4, 4, 4)).astype(np.float32)
-    x_qp = q.choose_qparams(jnp.float32(x.min()), jnp.float32(x.max()))
-    with pytest.raises(ValueError, match="ambiguous"):
-        nc.nc_conv2d(x, wq, x_qp, w_qp, layer_spec=spec, plan=plan,
-                     compressed=True)
 
 
 def test_compression_off_plan_equal_and_carryover(reduced_specs):
