@@ -114,9 +114,12 @@ class Backend:
     dispatches once per layer (more calls only where an operand grid
     would pass ``max_lane_words``), instead of once per plan tile.
 
-    ``compiled_pools`` declares a device path: ``nc_maxpool2d`` and
-    ``nc_avgpool2d`` run as one compiled reduce-window call on it, and
-    ``host`` keeps their bit-serial walk as the byte-exact reference."""
+    ``emulates_walks`` declares that the backend steps the bit-level
+    walks outside the dot (the pools' and the §IV-D min/max tree) in
+    numpy, as ``host`` does for the byte-exact reference; on the others
+    ``nc_maxpool2d`` and ``nc_avgpool2d`` run as one compiled
+    reduce-window call and ``nc_minmax`` as one exact integer reduction,
+    and each charges its walk's closed-form cycles."""
 
     name: str
     # accumulator widths executed natively (None = any)
@@ -127,7 +130,7 @@ class Backend:
     # cap on one operand's word-grid size (None = unbounded)
     max_lane_words: int | None
     layer_calls: bool  # takes a layer's whole pass list in one call
-    compiled_pools: bool  # pools run as one compiled call, not the walk
+    emulates_walks: bool  # pools and min/max step their bit-level walks
     dot_words: Callable[..., np.ndarray]
 
     def supports_acc(self, acc_bits: int) -> bool:
@@ -373,12 +376,12 @@ def _pallas_dot_words(xw, ww, *, K: int, acc_bits: int,
 register_backend(Backend(
     name="host", acc_bits=None, w4a4=True, compressed_planes=True,
     integrity=True, max_lane_words=None, layer_calls=False,
-    compiled_pools=False, dot_words=_host_dot_words))
+    emulates_walks=True, dot_words=_host_dot_words))
 register_backend(Backend(
     name="jit", acc_bits=None, w4a4=True, compressed_planes=True,
     integrity=True, max_lane_words=None, layer_calls=False,
-    compiled_pools=True, dot_words=_jit_dot_words))
+    emulates_walks=False, dot_words=_jit_dot_words))
 register_backend(Backend(
     name="pallas", acc_bits=(24, 32), w4a4=True,
     compressed_planes=True, integrity=True, max_lane_words=1 << 22,
-    layer_calls=True, compiled_pools=True, dot_words=_pallas_dot_words))
+    layer_calls=True, emulates_walks=False, dot_words=_pallas_dot_words))

@@ -45,7 +45,9 @@ bucketed compiled engine (see core/bitserial.py) for sweep workloads.
 
 :func:`nc_minmax` is the §IV-D in-cache dynamic-range reduction: a
 bit-serial log tree of subtract + tag-masked copies over packed lanes —
-only the two scalars per image ever leave the cache.
+only the two scalars per image ever leave the cache.  ``host`` emulates
+that tree, as it does the pools' walks; the device backends take the
+exact result and charge the tree's cycles.
 
 The TPU-fast path lives in repro/kernels.
 """
@@ -795,6 +797,14 @@ def nc_conv2d(
     return result, total_cycles, stats
 
 
+def _walks(engine: str | None) -> bool:
+    """Whether the bit-level walks outside the dot (the pools and the
+    min/max tree) step bit by bit for ``engine``: on a backend that
+    ``emulates_walks`` (``host``) and on a call with no engine; the
+    device backends take the exact result and charge the closed form."""
+    return engine is None or _backends.get_backend(engine).emulates_walks
+
+
 def _pool_cycles(kind: str, B: int, E: int, F: int, window: int):
     """Emulated cycles of a ``kind`` ("max" or "avg") pool with B x E x F
     output lane groups: the closed forms the bit-serial walk asserts step
@@ -855,11 +865,11 @@ def nc_maxpool2d(x_q: jax.Array, window: int, stride: int,
     Accepts ``[H, W, C]`` or ``[B, H, W, C]``; all B x E x F x C output
     lanes advance in lockstep through the window^2 - 1 sequential max
     steps (cycle count stays per-pixel, as the per-pixel formulation
-    reported it).  On a backend with ``compiled_pools`` (``engine``) the
-    values come from one compiled reduce-window call and the cycles from
-    the walk's closed form; without an engine, or on ``host``, the
+    reported it).  On a device backend (``engine``; see :func:`_walks`)
+    the values come from one compiled reduce-window call and the cycles
+    from the walk's closed form; without an engine, or on ``host``, the
     bit-serial walk runs."""
-    if engine is not None and _backends.get_backend(engine).compiled_pools:
+    if not _walks(engine):
         return _pool_compiled("max", x_q, window, stride, padding)
     xin = np.asarray(x_q, np.int64)
     batched = xin.ndim == 4
@@ -893,7 +903,7 @@ def nc_avgpool2d(x_q: jax.Array, window: int, stride: int,
     Accepts ``[H, W, C]`` or ``[B, H, W, C]``.  Cycles per output lane
     group: the widening sum tree over the window plus one 8-bit divide.
     ``engine`` selects the compiled call as in :func:`nc_maxpool2d`."""
-    if engine is not None and _backends.get_backend(engine).compiled_pools:
+    if not _walks(engine):
         return _pool_compiled("avg", x_q, window, stride, padding)
     xin = np.asarray(x_q, np.int64)
     batched = xin.ndim == 4
@@ -920,12 +930,17 @@ def nc_avgpool2d(x_q: jax.Array, window: int, stride: int,
     return jnp.asarray(out if batched else out[0], jnp.uint8), cycles
 
 
-def nc_minmax(x_q, bits: int = 32, signed: bool = False):
-    """§IV-D in-cache dynamic range: min AND max of quantized values via a
-    bit-serial log tree (subtract + tag-masked copy per halving step), run
-    entirely in packed word space — only the two scalars per row leave the
-    cache, exactly the "two numbers sent to the CPU" of the paper's
-    quantization pipeline.
+def nc_minmax(x_q, bits: int = 32, signed: bool = False,
+              engine: str | None = None):
+    """§IV-D in-cache dynamic range: min AND max of quantized values.
+
+    The modeled operation is a bit-serial log tree (subtract + tag-masked
+    copy per halving step) run in packed word space — only the two
+    scalars per row leave the cache, exactly the "two numbers sent to the
+    CPU" of the paper's quantization pipeline — and its cycles are what
+    is charged.  Where :func:`_walks` holds for ``engine`` (``host``, or
+    no engine) the tree is emulated bit by bit; the device backends take
+    its exact result, one integer min and max over the rows.
 
     ``x_q``: integer array whose LAST axis is reduced; leading axes (e.g.
     the image batch) are independent rows advancing in lockstep.  Rows are
@@ -935,26 +950,32 @@ def nc_minmax(x_q, bits: int = 32, signed: bool = False):
     plane is biased on the way in and the scalars un-biased on the way out
     (one extra cycle each way — an XOR pass on a single plane).
 
-    Returns ``(mins, maxs, cycles)`` — arrays shaped like the leading
-    axes — with ``cycles == bitserial.minmax_cycles(K, bits)``
+    Returns ``(mins, maxs, cycles)`` — int64 arrays shaped like the
+    leading axes — with ``cycles == bitserial.minmax_cycles(K, bits)``
     (+2 when ``signed``); all rows share the one lockstep tree.
     """
     x = np.asarray(x_q)
     lead = x.shape[:-1]
     K = x.shape[-1] if x.ndim else 1
-    rows = x.reshape(-1, K).astype(np.int64)
+    rows = x.reshape(-1, K).astype(np.int64, copy=False)
     bias = (1 << (bits - 1)) if signed else 0
-    u = ((rows + bias) & ((1 << bits) - 1)).astype(np.uint64)
-    P = 1 << max(0, (K - 1).bit_length())
-    padded = np.empty((u.shape[0], P), np.uint64)
-    padded[:, :K] = u
-    padded[:, K:] = u[:, :1]  # neutral pad: a copy of a real lane
-    pp = bs.pack_values(padded, bits, row_align=True)
-    (mn_pp, mx_pp), cycles = bs.bitserial_minmax(pp)
-    mn = bs.unpack_values(mn_pp).reshape(-1) - bias
-    mx = bs.unpack_values(mx_pp).reshape(-1) - bias
+    u = (rows + bias) & ((1 << bits) - 1)  # the lanes' bits-wide codes
+    if _walks(engine):
+        P = 1 << max(0, (K - 1).bit_length())
+        padded = np.empty((u.shape[0], P), np.uint64)
+        padded[:, :K] = u
+        padded[:, K:] = u[:, :1]  # neutral pad: a copy of a real lane
+        pp = bs.pack_values(padded, bits, row_align=True)
+        (mn_pp, mx_pp), cycles = bs.bitserial_minmax(pp)
+        mn = bs.unpack_values(mn_pp).reshape(-1)
+        mx = bs.unpack_values(mx_pp).reshape(-1)
+    else:
+        mn, mx = u.min(axis=-1), u.max(axis=-1)
+        cycles = bs.minmax_cycles(K, bits)
     if signed:
         cycles += 2  # sign-plane bias in + un-bias out
+    mn = mn.astype(np.int64) - bias
+    mx = mx.astype(np.int64) - bias
     return mn.reshape(lead), mx.reshape(lead), cycles
 
 

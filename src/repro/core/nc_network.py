@@ -28,10 +28,12 @@ occupancy for the sparse plan (:func:`network_occupancy`), and runs it
 (:func:`nc_forward`).
 
 Activations stay *quantized uint8 residents* between layers.  Each
-layer's dynamic range is computed IN-CACHE by the ``nc_minmax`` log tree
-(§IV-D): only the two integer scalars per image leave the array, the CPU
-answers with a fixed-point multiplier and zero point, and the
-requantization runs back in-cache.  No CPU-side float min/max touches an
+layer's dynamic range is modeled as computed IN-CACHE by the min/max log
+tree (§IV-D, ``nc_minmax``), whose cycles are charged: only the two
+integer scalars per image leave the array, the CPU answers with a
+fixed-point multiplier and zero point, and the requantization runs back
+in-cache.  ``host`` emulates the tree bit by bit; the device backends
+take its exact result.  No CPU-side float min/max touches an
 activation tensor in the layer loop; the only offline float ranges are
 the static weights'.  BatchNorm is inference-folded into a per-filter
 scale (multiplied into the filter before quantization) and a bias (an
@@ -435,14 +437,17 @@ def _requant_image(acc_b: np.ndarray, real_multiplier: float,
     return np.clip(rounded + zero_point, 0, 255).astype(np.uint8)
 
 
-def _requant_out(acc: np.ndarray, scales) -> tuple[np.ndarray, list, int]:
+def _requant_out(acc: np.ndarray, scales,
+                 engine: str) -> tuple[np.ndarray, list, int]:
     """The §IV-D requantization of a batch of int32 results ``acc`` whose
-    image ``b`` is in units of ``scales[b]``: the in-cache min/max tree,
+    image ``b`` is in units of ``scales[b]``: the in-cache min/max tree
+    (emulated on ``host``, its exact result on ``engine``'s device path),
     the CPU-side scalar step (two integers in, multiplier and zero point
     out), then the fixed-point requant.  Returns ``(uint8, qparams,
     min/max cycles)``."""
     B = acc.shape[0]
-    mn, mx, c_mm = nc.nc_minmax(acc.reshape(B, -1), bits=32, signed=True)
+    mn, mx, c_mm = nc.nc_minmax(acc.reshape(B, -1), bits=32, signed=True,
+                                engine=engine)
     yq = np.empty(acc.shape, np.uint8)
     out_qps = []
     for b in range(B):
@@ -454,6 +459,14 @@ def _requant_out(acc: np.ndarray, scales) -> tuple[np.ndarray, list, int]:
     return yq, out_qps, int(c_mm)
 
 
+def _minmax_path(ex: _Exec) -> str:
+    """The ``minmax`` stat of the spans that requantize: ``tree`` where the
+    backend emulates the min/max tree, ``reduce`` where it takes the exact
+    reduction."""
+    walk = _backends.get_backend(ex.engine).emulates_walks
+    return "tree" if walk else "reduce"
+
+
 def _nc_run_conv(name, actq, act_qps, op, ex: _Exec):
     _, r, s, m_, stride, pad = op[:6]
     relu = conv_activation(op) == "relu"
@@ -462,7 +475,7 @@ def _nc_run_conv(name, actq, act_qps, op, ex: _Exec):
     acc, cycles, stats = nc.nc_conv2d(
         actq, wq, act_qps, w_qp, stride, padding=pad, geom=geom,
         layer_spec=spec, plan=plan, engine=ex.engine, return_stats=True)
-    with TraceAnnotation("nc.conv.epilogue"):
+    with TraceAnnotation("nc.conv.epilogue", minmax=_minmax_path(ex)):
         acc = np.asarray(acc, np.int64)  # [B, E, F, M] int32 staging
         B = acc.shape[0]
         # §IV-D epilogue, all in-cache: integer bias add (BN-folded), the
@@ -476,7 +489,7 @@ def _nc_run_conv(name, actq, act_qps, op, ex: _Exec):
         acc = acc + bias_q[:, None, None, :]
         if relu:
             acc = np.maximum(acc, 0)
-        yq, out_qps, c_mm = _requant_out(acc, sxw)
+        yq, out_qps, c_mm = _requant_out(acc, sxw, ex.engine)
         cycles += c_mm
     cycles += B * plan.quant_passes * _REQUANT_PASS_CYCLES
     with TraceAnnotation("nc.accounting"):
@@ -513,8 +526,8 @@ def _nc_run_conv(name, actq, act_qps, op, ex: _Exec):
 def _nc_run_pool(name, actq, act_qps, op, ex: _Exec):
     kind, r, stride, pad = op
     pool = nc.nc_maxpool2d if kind == "maxpool" else nc.nc_avgpool2d
-    compiled = _backends.get_backend(ex.engine).compiled_pools
-    with TraceAnnotation("nc.pool", path="compiled" if compiled else "walk"):
+    walk = _backends.get_backend(ex.engine).emulates_walks
+    with TraceAnnotation("nc.pool", path="walk" if walk else "compiled"):
         out_q, cycles = pool(actq, r, stride, padding=pad, engine=ex.engine)
         out_q = np.asarray(out_q, np.uint8)
     with TraceAnnotation("nc.accounting"):
@@ -566,7 +579,8 @@ def _nc_residual(name, body, shortcut, ex: _Exec):
         raise ValueError(f"{name}: body {aq.shape} and shortcut {bq.shape} "
                          f"differ in shape")
     B = aq.shape[0]
-    with TraceAnnotation("nc.residual", layer=name):
+    with TraceAnnotation("nc.residual", layer=name,
+                         minmax=_minmax_path(ex)):
         acc = np.empty(aq.shape, np.int64)
         scales = []
         for b in range(B):
@@ -577,7 +591,7 @@ def _nc_residual(name, body, shortcut, ex: _Exec):
                 for x, qp in ((aq, a_qps), (bq, b_qps)))
             scales.append(s_c)
         acc = np.maximum(acc, 0)
-        yq, out_qps, c_mm = _requant_out(acc, scales)
+        yq, out_qps, c_mm = _requant_out(acc, scales, ex.engine)
     with TraceAnnotation("nc.accounting"):
         modeled = sim.modeled_layer_cycles(ex.plans[name], ex.geom, ex.const)
         ex.records.append(NCLayerReport(
@@ -732,9 +746,11 @@ def nc_forward(params: dict, x: jax.Array, config,
     and residual joins requantize in-cache between them.
 
     Activations stay quantized uint8 between layers; each layer's dynamic
-    range comes from the IN-CACHE ``nc_minmax`` log tree (§IV-D) — only
-    two integer scalars per image leave the array, and the requantization
-    runs back in-cache as a fixed-point multiply.  Quantization is
+    range is modeled as the IN-CACHE ``nc_minmax`` log tree (§IV-D), whose
+    cycles are charged — only two integer scalars per image leave the
+    array, and the requantization runs back in-cache as a fixed-point
+    multiply.  ``host`` emulates the tree bit by bit; the device backends
+    take its exact result.  Quantization is
     per-image, so batched outputs are bit-identical to single-image runs.
 
     ``engine`` names a registered backend (``core/backends.py``).
@@ -782,12 +798,15 @@ def nc_forward(params: dict, x: jax.Array, config,
     Profiler spans (recorded only while a profiler session runs): the
     call is one ``nc.forward``; each conv, pool, residual join and the FC
     one ``nc.layer`` with a ``layer`` stat naming it; inside them the
-    host stages ``nc.conv.epilogue`` (bias, ReLU, min/max tree, requant),
+    host stages ``nc.conv.epilogue`` (bias, ReLU, min/max, requant),
     ``nc.pool`` (with the ``path`` stat: ``compiled`` on a device
     backend, ``walk`` on ``host``), ``nc.residual`` (the join, with the
     ``layer`` stat) and
     ``nc.accounting`` (modeled cycles and the report), and ``nc.concat``
-    at each branch concatenation.  docs/SERVING.md lists every span.
+    at each branch concatenation.  A conv's requantizing
+    ``nc.conv.epilogue`` and ``nc.residual`` carry the ``minmax`` stat:
+    ``tree`` on ``host``, ``reduce`` on a device backend.
+    docs/SERVING.md lists every span.
     """
     xin = np.asarray(x, np.float32)
     batched = xin.ndim == 4

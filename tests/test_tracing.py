@@ -9,7 +9,9 @@ run inside
 Stage spans are leaves: they never nest in each other, every one of them
 appears, and together they cover most of a conv layer's ``nc.layer``
 span.  The join's ``nc.residual`` sits in its own ``nc.layer`` and in no
-stage span.  ``nc.pool`` names the path its pool took.
+stage span.  ``nc.pool`` names the path its pool took, and the
+requantizing ``nc.conv.epilogue`` and ``nc.residual`` the path of their
+min/max.
 """
 import jax
 import numpy as np
@@ -82,8 +84,8 @@ RESIDUAL = ("residual", [("conv", ("conv", 3, 3, 8, 1, "SAME", "linear"))],
 JOIN = "Unit_t_add"
 
 
-@pytest.fixture(scope="module")
-def residual_spans(x32, tmp_path_factory):
+def _trace_residual(engine, trace_dir):
+    """The nc.* spans of :data:`RESIDUAL` run on ``engine``."""
     specs = []
     nc_network._op_specs("Unit_t", "Unit_t", RESIDUAL, 9, 8, specs)
     plans = {p.spec.name: p
@@ -95,15 +97,19 @@ def residual_spans(x32, tmp_path_factory):
     actq = rng.integers(0, 256, size=(1, 9, 9, 8)).astype(np.uint8)
     qps = [q.QuantParams(scale=np.float32(1 / 255), zero_point=0)]
     records = []
-    trace_dir = tmp_path_factory.mktemp("residual")
     with jax.profiler.trace(str(trace_dir)):
         yq, _ = nc_network._nc_apply_op(
             actq, qps, "Unit_t", RESIDUAL, nc_network._Exec(
                 wpack, {s.name: s for s in specs}, plans, XEON_E5_35MB,
-                sim.SimConstants(), "host", records))
+                sim.SimConstants(), engine, records))
     assert yq.shape == (1, 9, 9, 8)
     assert [r.kind for r in records] == ["conv", "residual"]
     return _spans(trace_dir)
+
+
+@pytest.fixture(scope="module")
+def residual_spans(x32, tmp_path_factory):
+    return _trace_residual("host", tmp_path_factory.mktemp("residual"))
 
 
 def test_residual_join_span_sits_in_its_layer_span(residual_spans):
@@ -168,6 +174,24 @@ def test_pool_span_names_its_path(x32, tmp_path, engine, path):
     [stats] = [st for _, name, *_, st in _spans(tmp_path)
                if name == "nc.pool"]
     assert stats["path"] == path
+
+
+@pytest.mark.parametrize("engine,path", [
+    ("host", "tree"), ("jit", "reduce"), ("pallas", "reduce")])
+def test_requant_spans_name_their_minmax_path(x32, tmp_path, engine, path):
+    """The conv's requantizing ``nc.conv.epilogue`` and the join's
+    ``nc.residual`` say how the min/max ran, and no span opens inside
+    either (their self time stays the requant stage's)."""
+    spans = _trace_residual(engine, tmp_path)
+    carriers = [(t, s, e, st["minmax"]) for t, name, s, e, st in spans
+                if "minmax" in st]
+    names = sorted(name for t, name, s, e, st in spans if "minmax" in st)
+    assert names == ["nc.conv.epilogue", "nc.residual"]
+    assert [m for *_, m in carriers] == [path, path]
+    for thread, lo, hi, _ in carriers:
+        for t, name, s, e, _ in spans:
+            if t == thread and (s, e) != (lo, hi):
+                assert e <= lo or s >= hi or (s <= lo and hi <= e), name
 
 
 def test_serving_step_span_names_its_requests(tmp_path):
